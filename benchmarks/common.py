@@ -6,7 +6,7 @@ import time
 
 
 from repro import checkpoint as ck
-from repro.core import DQNAgent, EnvConfig, RLScheduler, TrainConfig, make_zoo, train_agent
+from repro.core import DQNAgent, EnvConfig, RLScheduler, TrainConfig, train_agent
 from repro.core.agent import DQNConfig
 from repro.core.env import CoScheduleEnv
 
@@ -28,10 +28,6 @@ def missing_keys(path: str, required) -> list[str]:
     with open(path) as f:
         data = json.load(f)
     return [k for k in required if k not in data]
-
-
-def get_zoo():
-    return make_zoo(dryrun_dir=DRYRUN_DIR if os.path.isdir(DRYRUN_DIR) else None)
 
 
 def trained_agent(zoo, window: int = 12, c_max: int = 4, episodes: int = 2000,

@@ -7,8 +7,8 @@ import time
 
 import numpy as np
 
-from benchmarks.common import emit, get_zoo, rl_scheduler
-from repro.core import POLICIES, Schedule, corun_time, solo_run_time, paper_queues
+from benchmarks.common import emit, rl_scheduler
+from repro.core import POLICIES, Schedule, corun_time, make_zoo, solo_run_time, paper_queues
 from repro.core.metrics import avg_app_slowdown, fairness, relative_throughput
 from repro.core.partition import Partition, Slice, enumerate_partitions
 from repro.core.workloads import zoo_by_class
@@ -29,7 +29,7 @@ def _pair_pool(zoo):
 # ---------------------------------------------------------------------------
 
 def fig3_share_sweep(fast=False):
-    zoo = get_zoo()
+    zoo = make_zoo()
     out = {}
     t0 = time.time()
     n = 0
@@ -47,7 +47,7 @@ def fig3_share_sweep(fast=False):
 # ---------------------------------------------------------------------------
 
 def fig4_bw_partitioning(fast=False):
-    zoo = get_zoo()
+    zoo = make_zoo()
     out = {}
     t0 = time.time()
     n = 0
@@ -68,7 +68,7 @@ def fig4_bw_partitioning(fast=False):
 def fig5_variants(fast=False):
     # mix with scale-heterogeneous jobs (the hierarchical option's home turf:
     # right-sizing slices for US jobs while big jobs share the rest)
-    zoo = get_zoo()
+    zoo = make_zoo()
     by = zoo_by_class(zoo)
     jobs = [by["CI"][0], by["MI"][0], by["US"][0], by["US"][-1]]
     styles = {"mps": [], "mig": [], "hier": []}
@@ -111,7 +111,7 @@ def _method_schedules(queues, zoo, window, c_max, fast):
 
 
 def fig8_throughput(fast=False, window=12, c_max=4):
-    zoo = get_zoo()
+    zoo = make_zoo()
     queues = paper_queues(zoo, window=window, per_kind=3)
     t0 = time.time()
     scheds = _method_schedules(queues, zoo, window, c_max, fast)
@@ -129,7 +129,7 @@ def fig8_throughput(fast=False, window=12, c_max=4):
 # ---------------------------------------------------------------------------
 
 def fig9_window(fast=False):
-    zoo = get_zoo()
+    zoo = make_zoo()
     out = {}
     t0 = time.time()
     for w in ((4, 8, 12) if fast else (4, 8, 12, 16)):
@@ -142,7 +142,7 @@ def fig9_window(fast=False):
 
 
 def fig10_cmax(fast=False):
-    zoo = get_zoo()
+    zoo = make_zoo()
     out = {}
     t0 = time.time()
     for c in (2, 3, 4):

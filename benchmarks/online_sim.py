@@ -103,6 +103,7 @@ from benchmarks.bench_gate import (
     VECSIM_SPEEDUP_FLOOR,
 )
 from benchmarks.common import emit, missing_keys
+from repro.compile_cache import enable_compile_cache
 from repro.core import (
     CoScheduleEnv, DQNAgent, EnvConfig, TrainConfig, make_zoo, train_agent,
     widen_dqn_params,
@@ -116,6 +117,7 @@ from repro.online import (
     TimeSharingPolicy, VectorizedClusterSimulator, VectorizedFleetSimulator,
     default_retrain_train_config,
 )
+from repro.online.vecsim import hash_split_max
 
 REQUIRED_KEYS = ("window", "n_arrivals", "traces", "rl_vs_time_sharing",
                  "dispatch_comparison", "arrival_aware", "sim_wall",
@@ -144,20 +146,6 @@ FLEET_NOTE = (
     "exact equality with the committed single-pod cells — the fleet "
     "refactor must not move the legacy numbers")
 
-
-def _hash_split_max(trace, pods, seed=0) -> int:
-    """Largest per-pod sub-stream under hash routing — sizes the
-    vectorized fleet's per-lane capacity."""
-    from repro.online.router import FleetView, PodView, make_router
-    router = make_router("hash", seed)
-    view = FleetView(pods=tuple(
-        PodView(idx=i, width=w, free=(True,) * w, pending=0, ready=0,
-                queue_units=0, busy_units=0) for i, w in enumerate(pods)))
-    counts: dict[int, int] = {}
-    for a in trace:
-        p = router.route(a, view)
-        counts[p] = counts.get(p, 0) + 1
-    return max(counts.values())
 
 ARRIVAL_NOTE = (
     "frozen-agent observation-mode comparison on identical traces: "
@@ -289,7 +277,7 @@ def _fleet_scale(zoo, agent, env_cfg, window, n, seed,
         cap = sum(pods) / N_UNITS
         trace = TRACE_FAMILIES["poisson"](zoo, n=n_vec, load=load,
                                           seed=seed, capacity=cap)
-        capacity = int(1.02 * _hash_split_max(trace, pods, seed)) + 8
+        capacity = int(1.02 * hash_split_max(trace, pods, seed)) + 8
         t0 = time.perf_counter()
         vec = VectorizedFleetSimulator(
             TimeSharingPolicy(),
@@ -798,6 +786,7 @@ def _bench_trace(tname, trace, agent, env_cfg, window, retrain_cfg,
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--fast", action="store_true", help="shrink the full run")
     ap.add_argument("--smoke", action="store_true",
@@ -873,7 +862,7 @@ def main() -> None:
         n = args.arrivals or 10_000
         seed = bench.get("seed", args.seed)
         episodes = args.episodes or bench["train_episodes"]
-        zoo = make_zoo(dryrun_dir=None)
+        zoo = make_zoo()
         env_cfg = EnvConfig(window=window, c_max=4)
         print("name,us_per_call,derived")
         # deterministic replication of the committed run's profile-only
@@ -908,7 +897,7 @@ def main() -> None:
         n = args.arrivals or max(400, bench["n_arrivals"])
         load = bench.get("load", args.load)
         seed = bench.get("seed", args.seed)
-        zoo = make_zoo(dryrun_dir=None)
+        zoo = make_zoo()
         print("name,us_per_call,derived")
         section = _telemetry_overhead(zoo, window, n, load, seed)
         bench["telemetry_overhead"] = section
@@ -936,7 +925,7 @@ def main() -> None:
         interval_min = (args.retrain_interval_min
                         or bench.get("retrain", {}).get("interval_min", 30.0))
         retrain_episodes = bench.get("retrain", {}).get("episodes", 240)
-        zoo = make_zoo(dryrun_dir=None)
+        zoo = make_zoo()
         env_cfg = EnvConfig(window=window, c_max=4)
         print("name,us_per_call,derived")
         # deterministic replication of the committed run's profile-only agent
@@ -968,7 +957,7 @@ def main() -> None:
         n = args.arrivals or bench["n_arrivals"]
         load = bench.get("load", args.load)
         seed = bench.get("seed", args.seed)
-        zoo = make_zoo(dryrun_dir=None)
+        zoo = make_zoo()
         print("name,us_per_call,derived")
         section = _vectorized_sim(zoo, window, n, load, seed,
                                   batch=args.sweep_batch)
@@ -994,7 +983,7 @@ def main() -> None:
         load = bench.get("load", args.load)
         seed = bench.get("seed", args.seed)
         episodes = args.episodes or bench["train_episodes"]
-        zoo = make_zoo(dryrun_dir=None)
+        zoo = make_zoo()
         env_cfg = EnvConfig(window=window, c_max=4)
         print("name,us_per_call,derived")
         # deterministic replication of the committed run's profile-only agent
@@ -1030,7 +1019,7 @@ def main() -> None:
         load = bench.get("load", args.load)
         seed = bench.get("seed", args.seed)
         episodes = args.episodes or bench["train_episodes"]
-        zoo = make_zoo(dryrun_dir=None)
+        zoo = make_zoo()
         env_cfg = EnvConfig(window=window, c_max=4)
         print("name,us_per_call,derived")
         # deterministic replication of the committed run's profile-only agent
@@ -1065,7 +1054,7 @@ def main() -> None:
         load = bench.get("load", args.load)
         seed = bench.get("seed", args.seed)
         episodes = args.episodes or bench["train_episodes"]
-        zoo = make_zoo(dryrun_dir=None)
+        zoo = make_zoo()
         env_cfg = EnvConfig(window=window, c_max=4)
         print("name,us_per_call,derived")
         # deterministic replication of the committed run's profile-only agent
@@ -1108,7 +1097,7 @@ def main() -> None:
         interval_min = args.retrain_interval_min or 30.0
         retrain_episodes = 240
 
-    zoo = make_zoo(dryrun_dir=None)
+    zoo = make_zoo()
     env_cfg = EnvConfig(window=window, c_max=4)
     print("name,us_per_call,derived")
     t0 = time.perf_counter()

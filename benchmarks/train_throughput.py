@@ -27,6 +27,7 @@ import sys
 import time
 
 from benchmarks.common import emit, missing_keys
+from repro.compile_cache import enable_compile_cache
 from repro.core import (
     EnvConfig, TrainConfig, make_zoo, train_agent, train_agent_scalar,
 )
@@ -159,6 +160,7 @@ def _telemetry_series(zoo, env_cfg, episodes: int, seed: int = 0) -> dict:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--fast", action="store_true", help="shrink measured episodes")
     ap.add_argument("--smoke", action="store_true",
@@ -185,7 +187,7 @@ def main() -> None:
                     help="write only the telemetry series and exit")
     args, _ = ap.parse_known_args()
     if args.telemetry_only:
-        zoo = make_zoo(dryrun_dir=None)
+        zoo = make_zoo()
         env_cfg = EnvConfig(window=args.window, c_max=4)
         tel = _telemetry_series(zoo, env_cfg, args.telemetry_episodes)
         with open(args.telemetry_out, "w") as f:
@@ -204,7 +206,7 @@ def main() -> None:
         vec_eps = args.vec_episodes or (200 if args.fast else 600)
     repeats = 1 if args.smoke else 2
 
-    zoo = make_zoo(dryrun_dir=None)
+    zoo = make_zoo()
     env_cfg = EnvConfig(window=args.window, c_max=4)
 
     print("name,us_per_call,derived")

@@ -2,10 +2,10 @@
 
 Jobs are training/serving steps of the 10 assigned architectures at scaled
 shape variants — the role Rodinia/CORAL play in the paper.  Profiles come
-from dry-run artifacts when available (experiments/dryrun), else from the
-analytic model.  Jobs are classified CI/MI/US with the paper's procedure and
-queues are drawn per the paper's mix recipes (X-dominant = 50% X, rest
-round-robin; Balanced = round-robin).
+from the analytic model, or from dry-run artifacts when a directory of them
+is passed explicitly (``make_zoo(dryrun_dir=...)``).  Jobs are classified
+CI/MI/US with the paper's procedure and queues are drawn per the paper's
+mix recipes (X-dominant = 50% X, rest round-robin; Balanced = round-robin).
 """
 from __future__ import annotations
 
@@ -58,8 +58,9 @@ _ZOO_SPEC: list[tuple[str, str, int, int]] = [
 _TARGETS = (90.0, 150.0, 120.0, 60.0, 180.0, 75.0, 135.0)
 
 
-def make_zoo(dryrun_dir: str | None = "experiments/dryrun") -> list[JobProfile]:
-    """All zoo jobs with profiles; dry-run-backed where records exist."""
+def make_zoo(dryrun_dir: str | None = None) -> list[JobProfile]:
+    """All zoo jobs with profiles: analytic by default, dry-run-backed where
+    ``dryrun_dir`` is given and holds a record for the job."""
     dr = load_dryrun_profiles(dryrun_dir) if dryrun_dir else {}
     jobs: list[JobProfile] = []
     for i, (arch, shape_id, bd, sd) in enumerate(_ZOO_SPEC):

@@ -11,7 +11,7 @@ Grid ``(B, Hkv, num_kv_blocks)`` — kv sweep innermost/sequential. For each
 block: scores tile is ``(g_pad, block_k)`` where ``g_pad`` pads the GQA group
 to the 8-row sublane minimum. Running (m, l, acc) live in fp32 VMEM scratch.
 Ragged sequence lengths are masked via an iota compare against a per-batch
-length scalar (SMEM-resident (1,1) block).
+length scalar read from the SMEM-resident length vector.
 """
 from __future__ import annotations
 
@@ -39,7 +39,7 @@ def _decode_kernel(
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    length = len_ref[0]
+    length = len_ref[pl.program_id(0)]
 
     # Skip blocks entirely past the valid prefix (dense stream otherwise).
     @pl.when(ki * block_k < length)
@@ -92,7 +92,9 @@ def decode_attention_kernel(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1,), lambda b, qi, ki: (b,), memory_space=pltpu.SMEM),
+            # whole (B*Hkv,) length vector in SMEM: Mosaic refuses rank-1
+            # blocks narrower than the array's 128-wide tiling
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, g_pad, d), lambda b, qi, ki: (b, 0, 0)),
             pl.BlockSpec((1, block_k, d), lambda b, qi, ki: (b, ki, 0)),
             pl.BlockSpec((1, block_k, d), lambda b, qi, ki: (b, ki, 0)),

@@ -20,10 +20,12 @@ def main():
     ap.add_argument("--per-kind", type=int, default=3)
     args = ap.parse_args()
 
-    from benchmarks.common import get_zoo, trained_agent
-    from repro.core import POLICIES, RLScheduler, paper_queues, summarize, validate_schedule
+    from benchmarks.common import trained_agent
+    from repro.core import (
+        POLICIES, RLScheduler, make_zoo, paper_queues, summarize, validate_schedule,
+    )
 
-    zoo = get_zoo()
+    zoo = make_zoo()
     agent, env_cfg = trained_agent(zoo, args.window, args.c_max, episodes=args.episodes)
     sched = RLScheduler(agent, env_cfg)
     queues = paper_queues(zoo, window=args.window, per_kind=args.per_kind)

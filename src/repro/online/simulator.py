@@ -472,6 +472,56 @@ class SimResult:
         }
 
 
+def times_close(a: float, b: float) -> bool:
+    """Clock equality to float32 resolution (device lanes vs the float64
+    heap): an absolute floor for near-zero waits, relative for
+    late-horizon timestamps."""
+    return abs(a - b) <= max(0.05, 1e-4 * max(abs(a), abs(b)))
+
+
+_DECISION_FIELDS = ("name", "binary", "units", "partition", "group_size",
+                    "backfilled", "pod")
+_TIME_FIELDS = ("dispatch", "finish", "wait", "turnaround")
+
+
+def decision_diffs(ref: SimResult, res: SimResult) -> list[str]:
+    """Decision-level differences between two runs of one trace.
+
+    One entry per job record whose placement decision (units, partition,
+    group size, backfill flag, pod) or clock (to :func:`times_close`)
+    differs, plus one per differing run count, timeline segment or busy
+    time.  Empty means the engines made the same decisions — the parity
+    contract of the vectorized engine against this heap reference.
+    """
+    if len(ref.jobs) != len(res.jobs):
+        return [f"job count {len(ref.jobs)} != {len(res.jobs)}"]
+    out: list[str] = []
+    key = lambda r: (r.arrival, r.name)  # noqa: E731
+    for a, b in zip(sorted(ref.jobs, key=key), sorted(res.jobs, key=key)):
+        bad = [f"{f} {getattr(a, f)!r} != {getattr(b, f)!r}"
+               for f in _DECISION_FIELDS if getattr(a, f) != getattr(b, f)]
+        bad += [f"{f} {getattr(a, f)} != {getattr(b, f)}"
+                for f in _TIME_FIELDS
+                if not times_close(getattr(a, f), getattr(b, f))]
+        if bad:
+            out.append(f"job {a.name}@{a.arrival}: " + ", ".join(bad))
+    for f in ("dispatches", "backfills", "refits"):
+        if getattr(ref, f) != getattr(res, f):
+            out.append(f"{f} {getattr(ref, f)} != {getattr(res, f)}")
+    if len(ref.timeline) != len(res.timeline):
+        out.append(f"segments {len(ref.timeline)} != {len(res.timeline)}")
+    else:
+        for i, (s, t) in enumerate(zip(ref.timeline, res.timeline)):
+            if (t.slices != s.slices or t.partition != s.partition
+                    or t.backfilled != s.backfilled
+                    or not (times_close(s.t0, t.t0)
+                            and times_close(s.t1, t.t1))):
+                out.append(f"segment {i}: {s} != {t}")
+    if not times_close(ref.busy_time, res.busy_time):
+        out.append(f"busy_time {ref.busy_time} != {res.busy_time}")
+    return out
+
+
 @dataclass
 class _Run:
     """A dispatched group awaiting (or holding) slice units on its pod."""

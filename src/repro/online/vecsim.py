@@ -147,6 +147,7 @@ class TraceArrays(NamedTuple):
     """One compiled trace: sorted arrival lanes, padded to ``capacity``."""
 
     t: jnp.ndarray               # (A,) f32 — sorted arrival times
+    t_lo: jnp.ndarray            # (A,) f32 — float64 residual t - f32(t)
     job: jnp.ndarray             # (A,) i32 — row into the job table
     n: jnp.ndarray               # ()   i32 — live arrivals (rest padding)
 
@@ -165,6 +166,7 @@ class _State(NamedTuple):
     """The whole simulation as fixed-shape lanes (A = capacity, R = ring)."""
 
     now: jnp.ndarray             # () f32
+    now_lo: jnp.ndarray          # () f32 — clock residual (_clock_add)
     pend_lo: jnp.ndarray         # () i32 — first undispatched admitted arrival
     pend_hi: jnp.ndarray         # () i32 — first un-admitted arrival
     profiled: jnp.ndarray        # (J,) bool — repository bitmap (first sight)
@@ -178,6 +180,7 @@ class _State(NamedTuple):
     # claim table: outstanding FREE events
     c_active: jnp.ndarray        # (N_UNITS,) bool
     c_t1: jnp.ndarray            # (N_UNITS,) f32 — expiry
+    c_lo: jnp.ndarray            # (N_UNITS,) f32 — expiry residual
     c_mask: jnp.ndarray          # (N_UNITS, N_UNITS) bool — claimed units
     # busy-span accounting (union over units, like the heap)
     n_busy: jnp.ndarray          # () i32
@@ -198,6 +201,7 @@ class _State(NamedTuple):
     g_arr: jnp.ndarray           # (A,) i32 — arrival index (A = unused)
     g_job: jnp.ndarray           # (A,) i32 — row into the job table
     g_t0: jnp.ndarray            # (A,) f32 — placement time
+    g_lo: jnp.ndarray            # (A,) f32 — placement time residual
     g_pack: jnp.ndarray          # (A,) i32 — (pseq << 4)|(start << 1)|bf
 
 
@@ -278,6 +282,42 @@ def _percentile(x, valid, q):
     return jnp.where(n > 0, out, jnp.float32(0.0))
 
 
+# The clock is a float32 pair (value, residual).  A busy lane chains
+# ``expiry = now + duration`` through thousands of events, and plain float32
+# addition drops up to half an ulp at each link: on a 10^4-arrival fleet
+# lane that drift reaches seconds by a 5e5-s horizon, far past float32
+# resolution of the float64 heap clock.  The residual keeps what each add
+# drops, so the value stays the float32 rounding of the float64 clock.
+# Decisions compare values only; the residuals enter elapsed times
+# (``_since``), event ties and the host-side float64 placement times.
+
+def _clock_add(hi, lo, dt):
+    """Compensated ``(hi + lo) + dt`` -> ``(value, residual)`` (two-sum)."""
+    s = hi + dt
+    bb = s - hi
+    err = (hi - (s - bb)) + (dt - bb) + lo
+    out = s + err
+    return out, err - (out - s)
+
+
+def _since(hi, lo, t, t_lo):
+    """Elapsed ``(hi + lo) - (t + t_lo)`` between two clock pairs."""
+    return (hi - t) + (lo - t_lo)
+
+
+def _next_event(st, trace: TraceArrays):
+    """The clock pair of the next event: the pending arrival or the
+    earliest claim expiry, whichever comes first."""
+    i = jnp.clip(st.pend_hi, 0, trace.t.shape[0] - 1)
+    t_arr = jnp.where(st.pend_hi < trace.n, trace.t[i], _INF)
+    exp = jnp.where(st.c_active, st.c_t1, _INF)
+    k = jnp.argmin(exp)
+    t_free, lo_arr, lo_free = exp[k], trace.t_lo[i], st.c_lo[k]
+    from_arr = (t_arr < t_free) | ((t_arr == t_free) & (lo_arr < lo_free))
+    return (jnp.where(from_arr, t_arr, t_free),
+            jnp.where(from_arr, lo_arr, lo_free))
+
+
 # ------------------------------------------------------------ state updates
 #
 # Every update below is *predicated* on a ``do`` flag instead of wrapped in
@@ -301,15 +341,18 @@ def _place(st: _State, jobs: JobTable, slot, start, backfilled, do) -> _State:
     rt = jnp.where(do, slot, st.r_active.shape[0])
     pack = ((st.place_seq << 4) | (start << 1)
             | jnp.where(backfilled, jnp.int32(1), jnp.int32(0)))
+    t1, t1_lo = _clock_add(st.now, st.now_lo, dur)
     return st._replace(
         free=st.free & ~mask,
         busy_t0=jnp.where(do & (st.n_busy == 0), st.now, st.busy_t0),
         n_busy=st.n_busy + doi * w,
         c_active=st.c_active.at[ct].set(True, mode="drop"),
-        c_t1=st.c_t1.at[ct].set(st.now + dur, mode="drop"),
+        c_t1=st.c_t1.at[ct].set(t1, mode="drop"),
+        c_lo=st.c_lo.at[ct].set(t1_lo, mode="drop"),
         c_mask=st.c_mask.at[ct].set(mask, mode="drop"),
         slice_busy=st.slice_busy + jnp.where(mask, dur, 0.0),
         g_t0=st.g_t0.at[gt].set(st.now, mode="drop"),
+        g_lo=st.g_lo.at[gt].set(st.now_lo, mode="drop"),
         g_pack=st.g_pack.at[gt].set(pack, mode="drop"),
         place_seq=st.place_seq + doi,
         r_active=st.r_active.at[rt].set(False, mode="drop"),
@@ -446,21 +489,22 @@ def _build_run(window: int, backfill: bool, capacity: int,
         J = jobs.width.shape[0]
         f32, i32 = jnp.float32, jnp.int32
         st = _State(
-            now=f32(0.0), pend_lo=i32(0), pend_hi=i32(0),
+            now=f32(0.0), now_lo=f32(0.0), pend_lo=i32(0), pend_hi=i32(0),
             profiled=jnp.zeros(J, dtype=bool),
             free=_UNIT_IDX < width,
             r_active=jnp.zeros(R, dtype=bool),
             r_seq=jnp.zeros(R, i32), r_win=jnp.zeros(R, i32),
             r_grp=jnp.zeros(R, i32), next_seq=i32(0),
             c_active=jnp.zeros(N_UNITS, dtype=bool),
-            c_t1=jnp.zeros(N_UNITS, f32),
+            c_t1=jnp.zeros(N_UNITS, f32), c_lo=jnp.zeros(N_UNITS, f32),
             c_mask=jnp.zeros((N_UNITS, N_UNITS), dtype=bool),
             n_busy=i32(0), busy_t0=f32(0.0), busy_time=f32(0.0),
             slice_busy=jnp.zeros(N_UNITS, f32),
             dispatches=i32(0), backfills=i32(0), n_groups=i32(0),
             place_seq=i32(0), steps=i32(0), err=i32(0),
             g_arr=jnp.full(A, A, i32), g_job=jnp.zeros(A, i32),
-            g_t0=jnp.zeros(A, f32), g_pack=jnp.zeros(A, i32),
+            g_t0=jnp.zeros(A, f32), g_lo=jnp.zeros(A, f32),
+            g_pack=jnp.zeros(A, i32),
         )
 
         def live(st: _State):
@@ -525,7 +569,7 @@ def _build_run(window: int, backfill: bool, capacity: int,
                 # wait histogram at placement: the placed group's arrival
                 # index lives in the (post-form_window) group log
                 arr = jnp.clip(st.g_arr[st.r_grp[slot]], 0, A - 1)
-                wait = st.now - trace.t[arr]
+                wait = _since(st.now, st.now_lo, trace.t[arr], trace.t_lo[arr])
                 b = jnp.searchsorted(_WAIT_EDGES, wait,
                                      side="left").astype(jnp.int32)
                 nb = ms.wait_hist.shape[0]
@@ -540,10 +584,8 @@ def _build_run(window: int, backfill: bool, capacity: int,
 
             # --- no service progress: advance the clock one event batch
             adv = ~progress
-            t_arr = jnp.where(st.pend_hi < trace.n,
-                              trace.t[jnp.clip(st.pend_hi, 0, A - 1)], _INF)
-            t_free = jnp.min(jnp.where(st.c_active, st.c_t1, _INF))
-            now = jnp.where(adv, jnp.minimum(t_arr, t_free), st.now)
+            t_next, lo_next = _next_event(st, trace)
+            now = jnp.where(adv, t_next, st.now)
             # drain every coincident event: admit all arrivals with t<=now.
             # The trace is sorted and everything <= the old clock is already
             # admitted, so the new cursor is just the count of t <= now
@@ -568,7 +610,8 @@ def _build_run(window: int, backfill: bool, capacity: int,
                     busy_unit_int=ms.busy_unit_int
                     + st.n_busy.astype(jnp.float32) * dt)
             st = st._replace(
-                now=now, pend_hi=pend_hi, free=st.free | freed,
+                now=now, now_lo=jnp.where(adv, lo_next, st.now_lo),
+                pend_hi=pend_hi, free=st.free | freed,
                 c_active=st.c_active & ~rel, n_busy=n_busy,
                 busy_time=busy_time, steps=steps,
                 err=st.err | jnp.where(steps > max_steps,
@@ -671,12 +714,15 @@ def compile_trace(trace: list[Arrival], capacity: int,
         if r == len(jobs):
             jobs.append(a.profile)
         rows.append(r)
+    t64 = np.array([a.t for a in order], np.float64)
     t = np.full(capacity, np.inf, np.float32)
-    t[:len(order)] = [a.t for a in order]
+    t[:len(order)] = t64
+    t_lo = np.zeros(capacity, np.float32)
+    t_lo[:len(order)] = t64 - t[:len(order)]
     job = np.zeros(capacity, np.int32)
     job[:len(rows)] = rows
-    return TraceArrays(t=jnp.asarray(t), job=jnp.asarray(job),
-                       n=jnp.int32(len(order))), order
+    return TraceArrays(t=jnp.asarray(t), t_lo=jnp.asarray(t_lo),
+                       job=jnp.asarray(job), n=jnp.int32(len(order))), order
 
 
 def build_job_table(jobs: list) -> JobTable:
@@ -692,6 +738,13 @@ def build_job_table(jobs: list) -> JobTable:
                     solo8=jnp.asarray(solo8, jnp.float32))
 
 
+def _placement_times(st, g_n: int) -> np.ndarray:
+    """Float64 placement times of a lane's first ``g_n`` groups: each
+    clock pair summed on the host."""
+    return (np.asarray(st.g_t0, np.float64)[:g_n]
+            + np.asarray(st.g_lo, np.float64)[:g_n])
+
+
 def _emit_lane(st: _State, jt: JobTable, records: list[JobRecord],
                pod: int = 0) -> list[Segment]:
     """Scatter one engine lane's group log into its (sorted-subtrace-
@@ -700,9 +753,9 @@ def _emit_lane(st: _State, jt: JobTable, records: list[JobRecord],
     fleet wrappers."""
     g_n = int(st.n_groups)
     g_arr = np.asarray(st.g_arr)[:g_n]
-    g_t0 = np.asarray(st.g_t0)[:g_n]
+    g_t0 = _placement_times(st, g_n)
     g_job = np.asarray(st.g_job)[:g_n]
-    g_dur = np.asarray(jt.dur)[g_job]
+    g_dur = np.asarray(jt.dur, np.float64)[g_job]
     g_w = np.asarray(jt.width)[g_job]
     pack = np.asarray(st.g_pack)[:g_n]
     g_pseq, g_start, g_bf = pack >> 4, (pack >> 1) & 7, (pack & 1) == 1
@@ -801,6 +854,7 @@ class _RLState(NamedTuple):
     and fallback/refit decompositions alike."""
 
     now: jnp.ndarray             # () f32
+    now_lo: jnp.ndarray          # () f32
     pend_lo: jnp.ndarray         # () i32
     pend_hi: jnp.ndarray         # () i32
     profiled: jnp.ndarray        # (J,) bool
@@ -812,6 +866,7 @@ class _RLState(NamedTuple):
     next_seq: jnp.ndarray        # () i32
     c_active: jnp.ndarray        # (N_UNITS,) bool
     c_t1: jnp.ndarray            # (N_UNITS,) f32
+    c_lo: jnp.ndarray            # (N_UNITS,) f32
     c_mask: jnp.ndarray          # (N_UNITS, N_UNITS) bool
     n_busy: jnp.ndarray          # () i32
     busy_t0: jnp.ndarray         # () f32
@@ -834,6 +889,7 @@ class _RLState(NamedTuple):
     g_ft: jnp.ndarray            # (A, C) f32 — per-slot finish offsets
     g_start: jnp.ndarray         # (A, C) i32 — per-slice start offsets
     g_t0: jnp.ndarray            # (A,) f32 — placement time
+    g_lo: jnp.ndarray            # (A,) f32 — placement time residual
     g_pack: jnp.ndarray          # (A,) i32 — (pseq << 1) | backfilled
 
 
@@ -953,15 +1009,18 @@ def _build_run_rl(window: int, backfill: bool, capacity: int,
         ct = jnp.where(do, jnp.argmin(st.c_active).astype(i32), N_UNITS)
         rt = jnp.where(do, slot, R)
         pack = (st.place_seq << 1) | jnp.where(backfilled, i32(1), i32(0))
+        t1, t1_lo = _clock_add(st.now, st.now_lo, dur)
         return st._replace(
             free=st.free & ~mask,
             busy_t0=jnp.where(do & (st.n_busy == 0), st.now, st.busy_t0),
             n_busy=st.n_busy + w,
             c_active=st.c_active.at[ct].set(True, mode="drop"),
-            c_t1=st.c_t1.at[ct].set(st.now + dur, mode="drop"),
+            c_t1=st.c_t1.at[ct].set(t1, mode="drop"),
+            c_lo=st.c_lo.at[ct].set(t1_lo, mode="drop"),
             c_mask=st.c_mask.at[ct].set(mask, mode="drop"),
             slice_busy=st.slice_busy + jnp.where(mask, dur, 0.0),
             g_t0=st.g_t0.at[gt].set(st.now, mode="drop"),
+            g_lo=st.g_lo.at[gt].set(st.now_lo, mode="drop"),
             g_start=st.g_start.at[gt].set(starts, mode="drop"),
             g_pack=st.g_pack.at[gt].set(pack, mode="drop"),
             place_seq=st.place_seq + doi,
@@ -987,14 +1046,14 @@ def _build_run_rl(window: int, backfill: bool, capacity: int,
         else:
             roll0 = ()
         st0 = _RLState(
-            now=f32(0.0), pend_lo=i32(0), pend_hi=i32(0),
+            now=f32(0.0), now_lo=f32(0.0), pend_lo=i32(0), pend_hi=i32(0),
             profiled=jnp.zeros(Jp, dtype=bool),
             free=_UNIT_IDX < width,
             r_active=jnp.zeros(R, dtype=bool),
             r_seq=jnp.zeros(R, i32), r_win=jnp.zeros(R, i32),
             r_grp=jnp.zeros(R, i32), next_seq=i32(0),
             c_active=jnp.zeros(N_UNITS, dtype=bool),
-            c_t1=jnp.zeros(N_UNITS, f32),
+            c_t1=jnp.zeros(N_UNITS, f32), c_lo=jnp.zeros(N_UNITS, f32),
             c_mask=jnp.zeros((N_UNITS, N_UNITS), dtype=bool),
             n_busy=i32(0), busy_t0=f32(0.0), busy_time=f32(0.0),
             slice_busy=jnp.zeros(N_UNITS, f32),
@@ -1004,7 +1063,8 @@ def _build_run_rl(window: int, backfill: bool, capacity: int,
             g_size=jnp.zeros(A, i32), g_pidx=jnp.zeros(A, i32),
             g_uidx=jnp.zeros((A, C), i32), g_dur=jnp.zeros(A, f32),
             g_ft=jnp.zeros((A, C), f32), g_start=jnp.zeros((A, C), i32),
-            g_t0=jnp.zeros(A, f32), g_pack=jnp.zeros(A, i32))
+            g_t0=jnp.zeros(A, f32), g_lo=jnp.zeros(A, f32),
+            g_pack=jnp.zeros(A, i32))
 
         def live(st):
             return ((st.pend_hi < trace.n) | jnp.any(st.c_active)
@@ -1050,7 +1110,9 @@ def _build_run_rl(window: int, backfill: bool, capacity: int,
                 # pending depth left behind (float32 mirror of the heap's
                 # float64 snapshot — context parity is approximate)
                 busy_f = (~st.free).astype(jnp.float32)
-                age = st.now - trace.t[jnp.clip(pl_arr, 0, A - 1)]
+                pl_i = jnp.clip(pl_arr, 0, A - 1)
+                age = _since(st.now, st.now_lo, trace.t[pl_i],
+                             trace.t_lo[pl_i])
                 ages_f = jnp.where(
                     pl_valid,
                     jnp.log10(1.0 + jnp.maximum(age, 0.0)) / 6.0, 0.0)
@@ -1303,7 +1365,8 @@ def _build_run_rl(window: int, backfill: bool, capacity: int,
                 g2 = st.r_grp[slot]
                 arrm = jnp.clip(st.g_arr[g2], 0, A - 1)
                 memv = c_rng < st.g_size[g2]
-                waits = st.now - trace.t[arrm]
+                waits = _since(st.now, st.now_lo, trace.t[arrm],
+                               trace.t_lo[arrm])
                 b = jnp.searchsorted(_WAIT_EDGES, waits,
                                      side="left").astype(i32)
                 nb = ms.wait_hist.shape[0]
@@ -1322,8 +1385,9 @@ def _build_run_rl(window: int, backfill: bool, capacity: int,
                 gq = st.r_grp[slot]
                 arrq = jnp.clip(st.g_arr[gq], 0, A - 1)
                 memq = c_rng < st.g_size[gq]
-                wq = st.now - trace.t[arrq]
-                tq = st.now + st.g_ft[gq] - trace.t[arrq]
+                wq = _since(st.now, st.now_lo, trace.t[arrq],
+                            trace.t_lo[arrq])
+                tq = wq + st.g_ft[gq]
                 brow = jnp.where(do_place, st.r_win[slot], A)
                 roll = roll._replace(
                     w_wait=roll.w_wait.at[brow].add(
@@ -1333,10 +1397,8 @@ def _build_run_rl(window: int, backfill: bool, capacity: int,
             st = place_rl(st, slot, sstarts, sunion, do_bf, do_place)
 
             adv = ~do_place & ~want
-            t_arr = jnp.where(st.pend_hi < trace.n,
-                              trace.t[jnp.clip(st.pend_hi, 0, A - 1)], _INF)
-            t_free = jnp.min(jnp.where(st.c_active, st.c_t1, _INF))
-            now = jnp.where(adv, jnp.minimum(t_arr, t_free), st.now)
+            t_next, lo_next = _next_event(st, trace)
+            now = jnp.where(adv, t_next, st.now)
             pend_hi = jnp.where(
                 adv, jnp.sum(trace.t <= now, dtype=i32), st.pend_hi)
             rel = adv & st.c_active & (st.c_t1 <= now)
@@ -1355,7 +1417,8 @@ def _build_run_rl(window: int, backfill: bool, capacity: int,
                     busy_unit_int=ms.busy_unit_int
                     + st.n_busy.astype(jnp.float32) * dt)
             st = st._replace(
-                now=now, pend_hi=pend_hi, free=st.free | freed,
+                now=now, now_lo=jnp.where(adv, lo_next, st.now_lo),
+                pend_hi=pend_hi, free=st.free | freed,
                 c_active=st.c_active & ~rel, n_busy=n_busy,
                 busy_time=busy_time, steps=steps,
                 err=st.err | jnp.where(steps > max_steps,
@@ -1439,7 +1502,7 @@ def _emit_lane_rl(st: _RLState, jobs: list, parts: list,
     g_pidx = np.asarray(st.g_pidx)[:g_n]
     g_uidx = np.asarray(st.g_uidx)[:g_n]
     g_start = np.asarray(st.g_start)[:g_n]
-    g_t0 = np.asarray(st.g_t0)[:g_n]
+    g_t0 = _placement_times(st, g_n)
     pack = np.asarray(st.g_pack)[:g_n]
     g_pseq, g_bf = pack >> 1, (pack & 1) == 1
     segs: list[tuple[int, Segment]] = []
@@ -1607,9 +1670,10 @@ RLDispatchPolicy`, whose agent episodes then run in-graph at the
               param_sets=None):
         """Evaluate ``traces`` in one device call (one compiled program).
 
-        With ``devices`` (>= 2 and batch divisible), the batch axis is
-        sharded across host devices via ``pmap`` — the CPU-CI parallelism
-        of ``XLA_FLAGS=--xla_force_host_platform_device_count=N``.
+        With ``devices`` (>= 2), the batch axis is sharded across host
+        devices via ``pmap`` — the CPU-CI parallelism of
+        ``XLA_FLAGS=--xla_force_host_platform_device_count=N``; a batch
+        that does not divide across them raises ``ValueError``.
 
         With ``with_metrics=True`` (requires a ``telemetry=True`` engine)
         returns ``(SweepSummary, MetricsState)`` — the per-lane metric
@@ -1653,7 +1717,10 @@ RLDispatchPolicy`, whose agent episodes then run in-graph at the
             jt = build_job_table(jobs)
             args = (jt,)
         n_dev = len(devices) if devices else 1
-        if n_dev > 1 and len(traces) % n_dev == 0:
+        if n_dev > 1 and len(traces) % n_dev:
+            raise ValueError(f"sweep of {len(traces)} traces does not divide "
+                             f"across {n_dev} devices")
+        if n_dev > 1:
             shard = jax.tree.map(
                 lambda x: x.reshape((n_dev, len(traces) // n_dev)
                                     + x.shape[1:]), batch)
@@ -1680,6 +1747,22 @@ RLDispatchPolicy`, whose agent episodes then run in-graph at the
                                "exceeded (stuck trace?)")
         if err:
             raise RuntimeError(f"vectorized engine: error lanes {err:#x}")
+
+
+def _quiescent_view(pods) -> FleetView:
+    return FleetView(pods=tuple(
+        PodView(idx=i, width=w, free=(True,) * w, pending=0, ready=0,
+                queue_units=0, busy_units=0)
+        for i, w in enumerate(pods)))
+
+
+def hash_split_max(trace: list[Arrival], pods, seed: int = 0) -> int:
+    """Largest per-pod sub-stream of ``trace`` under hash routing — the
+    per-lane ``capacity`` a :class:`VectorizedFleetSimulator` needs."""
+    router, view = make_router("hash", seed), _quiescent_view(pods)
+    counts = np.bincount([router.route(a, view) for a in trace],
+                         minlength=len(pods))
+    return int(counts.max())
 
 
 class VectorizedFleetSimulator:
@@ -1794,10 +1877,7 @@ class VectorizedFleetSimulator:
         # static pre-split: same router object the heap constructs, fed a
         # quiescent FleetView (hash ignores the dynamic fields) — so the
         # assignment is bit-identical to the heap's at-arrival routing
-        view = FleetView(pods=tuple(
-            PodView(idx=i, width=w, free=(True,) * w, pending=0, ready=0,
-                    queue_units=0, busy_units=0)
-            for i, w in enumerate(cfg.pods)))
+        view = _quiescent_view(cfg.pods)
         sub: list[list[Arrival]] = [[] for _ in cfg.pods]
         sub_rec: list[list[JobRecord]] = [[] for _ in cfg.pods]
         for a, rec in zip(order, records):

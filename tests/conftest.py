@@ -2,11 +2,20 @@
 any later import that touches XLA_FLAGS (e.g. repro.launch.dryrun helpers)
 cannot change the device count, and keep hypothesis CI-friendly.
 
+``JAX_PLATFORMS`` defaults to ``cpu`` before jax is imported, so the suite
+runs on the CPU backend even on a machine with a TPU attached (the chip is
+driven by ``chip_smoke.py``; ``tests/test_tpu_compile.py`` compiles for a
+described chip without touching one).
+
 Hypothesis is optional: when it is absent the profile registration is
 skipped and test modules fall back to the deterministic shim in
 ``_hypothesis_compat`` — the suite must never abort at collection because
 of a missing dev dependency."""
-import jax
+import os
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+
+import jax  # noqa: E402
 
 jax.devices()  # initialize backend now (1 CPU device)
 

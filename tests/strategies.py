@@ -14,9 +14,10 @@ One place owns the shapes the heap-vs-vectorized parity tests range over:
 * **Fleet topologies** — pod-width tuples led by the mandatory
   full-width pod; **engine knobs** — (window, backfill) pairs.
 * **Parity assertions** — :func:`close` (f32-device vs f64-heap
-  tolerance) and :func:`assert_parity` (decision-level equality),
-  shared by ``test_vecsim.py``, ``test_fleet.py``, and
-  ``test_parity_fuzz.py``.
+  tolerance) and :func:`assert_parity` (decision-level equality), thin
+  wrappers over the library's ``times_close``/``decision_diffs`` (which
+  ``chip_smoke.py`` also uses), shared by ``test_vecsim.py``,
+  ``test_fleet.py``, and ``test_parity_fuzz.py``.
 
 Import through the same hypothesis-or-shim seam as the test modules; the
 generators only use the surface ``_hypothesis_compat`` implements
@@ -33,8 +34,9 @@ except ImportError:
 from repro.core import make_zoo
 from repro.core.partition import N_UNITS
 from repro.online import Arrival, TRACE_FAMILIES
+from repro.online.simulator import decision_diffs, times_close
 
-ZOO = make_zoo(dryrun_dir=None)
+ZOO = make_zoo()
 
 FAMILIES = tuple(sorted(TRACE_FAMILIES))
 HINT_WIDTHS = (1, 2, 4, 8)
@@ -115,36 +117,10 @@ def engine_knobs():
 
 # ------------------------------------------------------ parity assertions
 
-def close(a, b):
-    # f32 lanes vs f64 heap: absolute floor for near-zero waits, relative
-    # for late-horizon timestamps
-    return abs(a - b) <= max(0.05, 1e-4 * max(abs(a), abs(b)))
+close = times_close
 
 
 def assert_parity(h, v):
     """Decision-level equality + f32-resolution times between engines."""
-    assert len(v.jobs) == len(h.jobs)
-    key = lambda r: (r.arrival, r.name)  # noqa: E731
-    for a, b in zip(sorted(h.jobs, key=key), sorted(v.jobs, key=key)):
-        assert a.name == b.name and a.binary == b.binary
-        assert a.units == b.units, (a.name, a.units, b.units)
-        assert a.partition == b.partition, (a.name, a.partition, b.partition)
-        assert a.group_size == b.group_size, (a.name, a.group_size,
-                                              b.group_size)
-        assert a.backfilled == b.backfilled
-        assert a.pod == b.pod, (a.name, a.pod, b.pod)
-        assert close(a.dispatch, b.dispatch), (a.name, a.dispatch, b.dispatch)
-        assert close(a.finish, b.finish), (a.name, a.finish, b.finish)
-        assert close(a.wait, b.wait)
-        assert close(a.turnaround, b.turnaround)
-    assert v.dispatches == h.dispatches
-    assert v.backfills == h.backfills
-    assert v.refits == h.refits
-    # timeline in placement order: same slice ranges, same backfill flags
-    assert len(v.timeline) == len(h.timeline)
-    for s, t in zip(h.timeline, v.timeline):
-        assert t.slices == s.slices
-        assert t.partition == s.partition
-        assert t.backfilled == s.backfilled
-        assert close(s.t0, t.t0) and close(s.t1, t.t1)
-    assert close(h.busy_time, v.busy_time)
+    diffs = decision_diffs(h, v)
+    assert not diffs, f"{len(diffs)} differences: {diffs[:10]}"

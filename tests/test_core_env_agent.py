@@ -12,7 +12,7 @@ from repro.core.agent import DQNAgent, DQNConfig, _dqn_update
 from repro.core.env import CoScheduleEnv
 from repro.core.network import dqn_apply, init_dqn, masked_argmax
 
-ZOO = make_zoo(dryrun_dir=None)
+ZOO = make_zoo()
 
 
 def _queue(n=6, seed=0):

@@ -16,7 +16,7 @@ from repro.core.env import CoScheduleEnv
 from repro.core.profiles import ProfileRepository
 from repro.core.workloads import make_queue
 
-ZOO = make_zoo(dryrun_dir=None)
+ZOO = make_zoo()
 RNG = np.random.default_rng(0)
 QUEUE = make_queue(ZOO, "balanced", 6, RNG)
 

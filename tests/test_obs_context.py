@@ -32,7 +32,7 @@ from repro.core.partition import N_UNITS
 from repro.core.scheduler import submission_protocol
 from repro.core.workloads import make_queue
 
-ZOO = make_zoo(dryrun_dir=None)
+ZOO = make_zoo()
 
 BASE = EnvConfig(window=6, c_max=3)
 CTX = EnvConfig(window=6, c_max=3, obs_context=True)

@@ -21,7 +21,7 @@ from repro.online import (
     TimeSharingPolicy, heavy_tailed_trace, poisson_trace,
 )
 
-ZOO = make_zoo(dryrun_dir=None)
+ZOO = make_zoo()
 ENV_CFG = EnvConfig(window=4, c_max=3)
 
 

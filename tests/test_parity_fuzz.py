@@ -22,6 +22,7 @@ examples share one random-init agent, so the jit compile count stays
 bounded across examples.
 """
 import numpy as np
+import pytest
 
 try:
     from hypothesis import given, settings, strategies as st
@@ -38,7 +39,7 @@ from repro.core.env import CoScheduleEnv, EnvConfig
 from repro.core.network import greedy_q_action
 from repro.core.partition import N_UNITS
 from repro.online import (
-    ClusterSimulator, SimConfig, TimeSharingPolicy,
+    ClusterSimulator, SimConfig, TRACE_FAMILIES, TimeSharingPolicy,
     VectorizedClusterSimulator, VectorizedFleetSimulator,
 )
 from repro.online.policies import RLDispatchPolicy
@@ -121,6 +122,21 @@ def test_rl_parity_adversarial_duplicate_tenants(trace):
 def test_ts_parity_adversarial_duplicate_tenants(trace):
     h = ClusterSimulator(TimeSharingPolicy(), window=8).run(trace)
     assert_parity(h, _vec_ts().run(trace))
+
+
+@pytest.mark.parametrize("engine", ["ts", "rl"])
+def test_parity_long_horizon_clock(engine):
+    """A busy lane chains thousands of ``expiry = now + duration`` links out
+    to a ~5e5-s horizon; the device clock must still track the f64 heap
+    clock to f32 resolution there (plain f32 adds drift by seconds)."""
+    trace = TRACE_FAMILIES["poisson"](ZOO, n=2500, load=0.85, seed=0)
+    if engine == "ts":
+        h = ClusterSimulator(TimeSharingPolicy(), window=8).run(trace)
+        v = _vec_ts(capacity=len(trace)).run(trace)
+    else:
+        h = ClusterSimulator(_rl_policy(), window=8).run(trace)
+        v = _vec_rl(capacity=len(trace)).run(trace)
+    assert_parity(h, v)
 
 
 # -------------------------------------------------------------- fleet RL

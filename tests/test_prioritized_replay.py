@@ -27,7 +27,7 @@ from repro.core.scheduler import RLScheduler
 from repro.core.train import _build_eval
 from repro.core.workloads import QUEUE_KINDS, make_queue
 
-ZOO = make_zoo(dryrun_dir=None)
+ZOO = make_zoo()
 
 
 def _block(v, n=4, dim=3, acts=2):

@@ -31,7 +31,7 @@ from repro.online import (
     TimeSharingPolicy, fragmented_trace, poisson_trace,
 )
 
-ZOO = make_zoo(dryrun_dir=None)
+ZOO = make_zoo()
 
 
 def _unit_set(seg):

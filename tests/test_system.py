@@ -25,7 +25,7 @@ from repro.core.agent import DQNConfig
 
 @pytest.fixture(scope="module")
 def trained():
-    zoo = make_zoo(dryrun_dir=None)
+    zoo = make_zoo()
     env_cfg = EnvConfig(window=6, c_max=4)
     agent, hist = train_agent(
         zoo, env_cfg,

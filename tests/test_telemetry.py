@@ -32,7 +32,7 @@ from repro.online import (
 from repro.online.telemetry import Histogram, entropy_bits
 from repro.online.vecsim import metrics_dict
 
-ZOO = make_zoo(dryrun_dir=None)
+ZOO = make_zoo()
 
 _ENGINES: dict = {}
 

@@ -27,7 +27,7 @@ from repro.online import (
 from repro.online.policies import RLDispatchPolicy
 from repro.online.retrain import default_retrain_online_config
 
-ZOO = make_zoo(dryrun_dir=None)
+ZOO = make_zoo()
 ENV_CFG = EnvConfig(window=4)
 _ENV = CoScheduleEnv(ENV_CFG)
 

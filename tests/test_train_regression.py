@@ -30,7 +30,7 @@ from repro.core import EnvConfig, TrainConfig, make_zoo, train_agent
 from repro.core.agent import DQNConfig
 from repro.core.train import TrainOnlineConfig, train_online
 
-ZOO = make_zoo(dryrun_dir=None)
+ZOO = make_zoo()
 GOLDEN = pathlib.Path(__file__).parent / "golden" / "train_agent_proxy_v1.npz"
 
 
@@ -90,3 +90,25 @@ def test_train_agent_matches_pre_pr_golden_checkpoint():
     assert [h["ep_reward"] for h in hist] == meta["ep_reward"]
     assert [h["heldout_throughput"] for h in hist] \
         == meta["heldout_throughput"]
+
+
+def _write_golden(path=GOLDEN):
+    """Record the pinned run's params/targets/history on the current stack."""
+    env_cfg, cfg = _pinned_cfg()
+    agent, hist = train_agent(ZOO, env_cfg, cfg)
+    params, targets = _leaves(agent)
+    meta = {"jax": jax.__version__, "backend": jax.default_backend(),
+            "x64": bool(jax.config.jax_enable_x64),
+            "machine": __import__("platform").machine(),
+            **{k: [h[k] for h in hist]
+               for k in ("eval_throughput", "heldout_throughput", "ep_reward")}}
+    arrays = {f"param_{i}": x for i, x in enumerate(params)}
+    arrays.update({f"target_{i}": x for i, x in enumerate(targets)})
+    np.savez(path, meta=json.dumps(meta), **arrays)
+    print(f"wrote {path}: {meta}")
+
+
+if __name__ == "__main__":
+    # Regenerate from a tree whose training path is known-good, on the CPU:
+    #   JAX_PLATFORMS=cpu PYTHONPATH=src python tests/test_train_regression.py
+    _write_golden()

@@ -138,6 +138,15 @@ def test_sweep_sharded_matches_unsharded():
                                    rtol=1e-6, atol=1e-6)
 
 
+def test_sweep_indivisible_across_devices_raises():
+    """A batch that cannot be split evenly over ``devices`` is refused
+    before any device call, never run quietly on one device."""
+    traces = [TRACE_FAMILIES["poisson"](ZOO, n=8, load=1.2, seed=s)
+              for s in range(3)]
+    with pytest.raises(ValueError, match="does not divide"):
+        _vec_engine(capacity=64).sweep(traces, devices=jax.devices()[:1] * 2)
+
+
 def test_capacity_overflow_raises_eagerly():
     """A trace longer than the event table must raise before the device
     program runs — never silently drop arrivals."""

@@ -19,7 +19,7 @@ from repro.core.env import CoScheduleEnv, VecCoScheduleEnv
 from repro.core.replay import replay_init, replay_push, replay_sample
 from repro.core.workloads import QUEUE_KINDS, make_queue
 
-ZOO = make_zoo(dryrun_dir=None)
+ZOO = make_zoo()
 
 
 def _rollout_pair(env_cfg, queue, seed):
