@@ -1,0 +1,166 @@
+"""What the serving drivers share: the cell's inputs on both sides of the
+comparison and the comparison itself.
+
+The program gets its own objects (``JobProfile``, ``Arrival``, a
+``DQNAgent`` holding the frozen params); the reference gets plain ones
+built from the same frozen files.  Nothing the program computes reaches
+the reference except the decisions it is judged on.
+"""
+from __future__ import annotations
+
+import json
+from pathlib import Path
+
+import ml_dtypes
+import numpy as np
+
+from bench.lib import traffic
+from bench.reference.compare import compare, group_ids
+from bench.reference.model import N_UNITS, jobs_from_snapshot
+from bench.reference.serve import (
+    RLPlanner, ReferenceAgent, ReferenceFleet, f32_clock, f64_clock,
+)
+
+BENCH = Path(__file__).resolve().parents[1]
+
+# operand precision of the agent's matrix products: what the configuration
+# states, and the next lower one for the control
+OPERANDS = {"float32": np.float32, "bfloat16": ml_dtypes.bfloat16}
+CLOCKS = {"float64": f64_clock, "float32": f32_clock}
+
+
+class Inputs:
+    """A cell's frozen inputs and its trace pool for one ``--seed``."""
+
+    def __init__(self, config: dict, traffic_spec: dict, seed: int):
+        self.config = config
+        self.zoo = traffic.load_zoo()
+        self.ref_jobs = jobs_from_snapshot(self.zoo)
+        capacity = sum(config["pods"]) / N_UNITS
+        self.pool = traffic.make_pool(traffic_spec, self.zoo, seed, capacity)
+        agent = config.get("agent")
+        self.params = (dict(np.load(BENCH / agent["params"]))
+                       if agent else None)
+
+    # ------------------------------------------------------------ program
+
+    def program_pool(self):
+        """The pool as the program's ``Arrival`` lists; one ``JobProfile``
+        object per zoo entry, shared by its arrivals."""
+        from repro.core.profiles import JobProfile
+        from repro.online.simulator import Arrival
+
+        keys = ("name", "arch", "shape", "steps", "flops_total",
+                "bytes_total", "coll_bytes_chip_pod", "n_coll_step",
+                "serial_s")
+        profs = [JobProfile(**{k: r[k] for k in keys}, meta=dict(r["meta"]))
+                 for r in self.zoo]
+        bins = [traffic.binary(r) for r in self.zoo]
+        return [[Arrival(t=float(t), binary=bins[p], profile=profs[p])
+                 for t, p in zip(times, picks)]
+                for times, picks in self.pool]
+
+    def program_policy(self):
+        """The configured policy, built from the program's classes."""
+        from repro.core import EnvConfig
+        from repro.online import RLDispatchPolicy, TimeSharingPolicy
+
+        if self.config["policy"] == "time_sharing":
+            return TimeSharingPolicy()
+        import jax.numpy as jnp
+        from repro.core.agent import DQNAgent
+        from repro.core.env import CoScheduleEnv
+
+        a = self.config["agent"]
+        env_cfg = EnvConfig(window=a["window"], c_max=a["c_max"])
+        env = CoScheduleEnv(env_cfg)
+        agent = DQNAgent(env.state_dim, env.n_actions, seed=0)
+        agent.params = {k: jnp.asarray(v, jnp.float32)
+                        for k, v in self.params.items()}
+        return RLDispatchPolicy(agent, env_cfg)
+
+    def sim_config(self):
+        from repro.online import SimConfig
+
+        c = self.config
+        return SimConfig(window=c["window"], backfill=c["backfill"],
+                         pods=tuple(c["pods"]), router=c["router"])
+
+    # ---------------------------------------------------------- reference
+
+    def reference_trace(self, k: int):
+        times, picks = self.pool[k]
+        return [(float(t), traffic.binary(self.zoo[p]), self.ref_jobs[p])
+                for t, p in zip(times, picks)]
+
+    def reference(self, clock: str, operands: str, tie_tol: float):
+        c = self.config
+        planner = None
+        if c["policy"] == "rl":
+            a = c["agent"]
+            planner = RLPlanner(ReferenceAgent(self.params, OPERANDS[operands]),
+                                a["window"], a["c_max"], tie_tol)
+        return ReferenceFleet(tuple(c["pods"]), c["window"], planner,
+                              CLOCKS[clock])
+
+
+def plain(res) -> dict:
+    """A program ``SimResult`` as the comparison's plain dict."""
+    segs = [[] for _ in res.pods]
+    for s in res.timeline:
+        segs[s.pod].append((s.t0, s.t1, s.jobs, s.partition,
+                            tuple(tuple(r) for r in s.slices), s.backfilled))
+    for p in segs:
+        p.sort(key=lambda s: s[0])
+    return {"records": [{"name": r.name, "binary": r.binary, "pod": r.pod,
+                         "dispatch": r.dispatch, "finish": r.finish,
+                         "group_size": r.group_size,
+                         "partition": r.partition, "units": r.units,
+                         "backfilled": r.backfilled} for r in res.jobs],
+            "dispatches": res.dispatches, "backfills": res.backfills,
+            "refits": res.refits, "busy_time": res.busy_time,
+            "segments": segs}
+
+
+def follow_keys(result: dict) -> list[tuple]:
+    """Per record: group size, partition, slice width, group, co-run time,
+    dispatch and backfill flag — what resolves the agent's near-ties in
+    the reference."""
+    recs = result["records"]
+    return [(r["group_size"], r["partition"], r["units"], g,
+             r["finish"] - r["dispatch"], r["dispatch"], r["backfilled"])
+            for r, g in zip(recs, group_ids(recs))]
+
+
+def judge(inputs: Inputs, served: dict[int, dict], check: dict) -> dict:
+    """Run the reference over each served trace of ``served`` (pool index
+    to plain program result) and compare.  Returns the compared numbers,
+    each the worst over the traces, with examples of what differed."""
+    worst = {"decisions_differing": 0, "clock_gap": 0.0}
+    rl = inputs.config["policy"] == "rl"
+    if rl:
+        worst["tie_gap"] = 0.0
+    examples: list[str] = []
+    for k, prog in sorted(served.items()):
+        ref = inputs.reference("float64", "float32", check["tie_tol"])
+        trace = inputs.reference_trace(k)
+        follow = (follow_keys(prog) if len(prog["records"]) == len(trace)
+                  else None)
+        out = ref.run(trace, follow=follow)
+        cmp = compare(prog, out)
+        worst["decisions_differing"] += cmp["decisions_differing"]
+        worst["clock_gap"] = max(worst["clock_gap"], cmp["clock_gap"])
+        if rl:
+            worst["tie_gap"] = max(worst["tie_gap"], out["tie_gap"])
+        examples += [f"trace {k}: {e}" for e in cmp["examples"]]
+    return {"numbers": worst, "examples": examples[:5]}
+
+
+def unserved(prog: dict) -> int:
+    return sum(1 for r in prog["records"]
+               if not (np.isfinite(r["dispatch"]) and np.isfinite(r["finish"])
+                       and r["dispatch"] <= r["finish"]))
+
+
+def load_json(path: Path) -> dict:
+    return json.loads(path.read_text())
