@@ -103,6 +103,7 @@ from benchmarks.bench_gate import (
     VECSIM_SPEEDUP_FLOOR,
 )
 from benchmarks.common import emit, missing_keys
+from repro import spans
 from repro.compile_cache import enable_compile_cache
 from repro.core import (
     CoScheduleEnv, DQNAgent, EnvConfig, TrainConfig, make_zoo, train_agent,
@@ -167,32 +168,14 @@ def _simulate(policy, trace, window, retrainer=None, mode="concurrent",
     use_vec = (engine == "vectorized" and retrainer is None
                and mode == "concurrent"
                and VectorizedClusterSimulator.supports(policy))
-    # --profile: shim the policy's decide() and the retrainer callable with
-    # wall-clock accumulators so each cell splits its sim_wall_s into
-    # sim / policy / retrain phases (heap cells only; the vectorized
-    # engine's policy work is compiled into the graph)
-    pt = None
-    on_tick = retrainer
-    if profile and not use_vec:
-        from repro.online.telemetry import PhaseTimer
-        pt = PhaseTimer()
-        orig_decide = policy.decide
-
-        def timed_decide(*a, **kw):
-            t = time.perf_counter()
-            try:
-                return orig_decide(*a, **kw)
-            finally:
-                pt.add("policy_s", time.perf_counter() - t)
-
-        policy.decide = timed_decide
-        if retrainer is not None:
-            def on_tick(now, sim, _rt=retrainer):
-                t = time.perf_counter()
-                try:
-                    _rt(now, sim)
-                finally:
-                    pt.add("retrain_s", time.perf_counter() - t)
+    # --profile: the span recorder splits each heap cell's sim_wall_s into
+    # sim / policy (repro.policy.decide) / retrain (repro.sim.tick) phases
+    # (heap cells only; the vectorized engine's policy work is compiled
+    # into the graph)
+    profiling = profile and not use_vec
+    if profiling:
+        spans.reset()
+        spans.enable()
     t0 = time.perf_counter()
     try:
         if use_vec:
@@ -203,21 +186,23 @@ def _simulate(policy, trace, window, retrainer=None, mode="concurrent",
             sim = ClusterSimulator(
                 policy, window=window, mode=mode,
                 tick_interval_s=retrainer.interval_s if retrainer else None,
-                on_tick=on_tick)
+                on_tick=retrainer)
             res = sim.run(trace)
     finally:
-        if pt is not None:
-            del policy.decide
+        if profiling:
+            spans.disable()
     out = res.summary()
     out["sim_wall_s"] = time.perf_counter() - t0
     out["engine"] = "vectorized" if use_vec else "heap"
-    if pt is not None:
-        phases = pt.as_dict()
-        phases.setdefault("policy_s", 0.0)
-        phases.setdefault("retrain_s", 0.0)
+    if profiling:
+        totals = spans.summary()
+        phases = {k: round(totals.get(name, {}).get("total_s", 0.0), 6)
+                  for k, name in (("policy_s", "repro.policy.decide"),
+                                  ("retrain_s", "repro.sim.tick"))}
         phases["sim_s"] = max(
             0.0, out["sim_wall_s"] - phases["policy_s"] - phases["retrain_s"])
         out["profile"] = phases
+        spans.reset()
     if retrainer is not None:
         out["retrains"] = len(retrainer.history)
         out["retrain_history"] = retrainer.history

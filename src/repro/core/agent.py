@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import spans
 from repro.core.network import (
     dqn_apply, greedy_q_action, init_dqn, masked_argmax,
 )
@@ -235,9 +236,17 @@ class DQNAgent:
             if self.rng.random() < self.epsilon:
                 return int(self.rng.choice(np.flatnonzero(mask)))
         # greedy selection routes through the same jitted kernel the
-        # vectorized engine closes over in-graph (see network.greedy_q_action)
-        return int(_greedy_action(self.params, jnp.asarray(state),
-                                  jnp.asarray(mask)))
+        # vectorized engine closes over in-graph (see network.greedy_q_action).
+        # One round trip: put the observation on the device, launch the
+        # forward pass (returns once enqueued), fetch the action back
+        span = spans.span
+        with span("repro.agent.act"):
+            with span("repro.agent.act.put"):
+                obs, valid = jnp.asarray(state), jnp.asarray(mask)
+            with span("repro.agent.act.launch"):
+                out = _greedy_action(self.params, obs, valid)
+            with span("repro.agent.act.fetch"):
+                return int(out)
 
     # -------------------------------------------------------------- learn
     def observe(self, s, a, r, s2, done, mask2) -> None:

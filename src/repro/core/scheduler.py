@@ -31,6 +31,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 
+from repro import spans
 from repro.core.agent import DQNAgent
 from repro.core.env import CoScheduleEnv, DispatchContext, EnvConfig
 from repro.core.partition import Partition, Slice, slice_label, solo_partition
@@ -178,15 +179,21 @@ class RLScheduler:
         """Greedy episode over ``queue``; ``context`` is the dispatch-time
         cluster snapshot an ``obs_context`` environment folds into the
         observation (ignored — zero block — otherwise)."""
-        env = CoScheduleEnv(self.env_cfg)
-        state, mask = env.reset(queue, context)
-        guard = 0
-        while not env.done:
-            action = self.agent.act(state, mask, greedy=True)
-            state, _, _, mask, _ = env.step(action)
-            guard += 1
-            assert guard < 10 * self.env_cfg.window, "scheduler failed to terminate"
-        return self._enforce_constraints(env.schedule)
+        span = spans.span
+        with span("repro.sched.episode"):
+            env = CoScheduleEnv(self.env_cfg)
+            with span("repro.sched.env"):
+                state, mask = env.reset(queue, context)
+            guard = 0
+            while not env.done:
+                action = self.agent.act(state, mask, greedy=True)
+                with span("repro.sched.env"):
+                    state, _, _, mask, _ = env.step(action)
+                guard += 1
+                assert guard < 10 * self.env_cfg.window, \
+                    "scheduler failed to terminate"
+            spans.count("repro.sched.steps", guard)
+            return self._enforce_constraints(env.schedule)
 
     def schedule_submissions(self, submissions: list[tuple[str, JobProfile | None]],
                              context: DispatchContext | None = None) -> Schedule:
@@ -212,13 +219,14 @@ class RLScheduler:
                                    on_window=on_window, context=context)
 
     def _enforce_constraints(self, sched: Schedule) -> Schedule:
-        solo = solo_partition()
-        out = Schedule()
-        for g, p in zip(sched.groups, sched.partitions):
-            if len(g) > 1 and corun_time(g, p) > solo_run_time(g):
-                self.stats.fallback_groups += 1
-                for j in g:
-                    out.add([j], solo)
-            else:
-                out.add(g, p)
-        return out
+        with spans.span("repro.sched.guard"):
+            solo = solo_partition()
+            out = Schedule()
+            for g, p in zip(sched.groups, sched.partitions):
+                if len(g) > 1 and corun_time(g, p) > solo_run_time(g):
+                    self.stats.fallback_groups += 1
+                    for j in g:
+                        out.add([j], solo)
+                else:
+                    out.add(g, p)
+            return out

@@ -104,8 +104,8 @@ from repro.online.simulator import (
     Arrival, ClusterSimulator, JobRecord, Segment, SimConfig, SimResult,
 )
 from repro.online.telemetry import (
-    DriftMonitor, MetricsRegistry, PhaseTimer, Telemetry, TraceRecorder,
-    WAIT_BUCKETS_S,
+    DriftMonitor, MetricsRegistry, Telemetry, TraceRecorder, WAIT_BUCKETS_S,
+    spans,
 )
 from repro.online.traces import (
     TRACE_FAMILIES, diurnal_trace, fragmented_trace, heavy_tailed_trace,
@@ -120,7 +120,7 @@ __all__ = [
     "Arrival", "ClusterSimulator", "DispatchPolicy", "DriftMonitor",
     "FleetView", "FragRouter", "GreedyPackerPolicy", "HashRouter",
     "JobRecord", "LeastLoadedRouter", "MetricsRegistry", "OnlineRetrainer",
-    "PhaseTimer", "PodView", "PolicyStats", "ROUTERS", "RLDispatchPolicy",
+    "PodView", "PolicyStats", "ROUTERS", "RLDispatchPolicy",
     "Router", "Segment", "SimConfig", "SimResult", "StaticPartitionPolicy",
     "SweepSummary", "TRACE_FAMILIES", "Telemetry", "TimeSharingPolicy",
     "TraceRecorder", "TrainRollout", "VectorizedClusterSimulator",
@@ -128,5 +128,5 @@ __all__ = [
     "default_retrain_online_config", "default_retrain_train_config",
     "diurnal_trace", "fragmented_trace",
     "heavy_tailed_trace", "make_rollout_collector", "make_router",
-    "mmpp_trace", "poisson_trace",
+    "mmpp_trace", "poisson_trace", "spans",
 ]

@@ -37,6 +37,7 @@ from __future__ import annotations
 import warnings
 from dataclasses import dataclass
 
+from repro import spans
 from repro.core.baselines import POLICIES, _best_for_group, time_sharing
 from repro.core.env import EnvConfig
 from repro.core.partition import enumerate_partitions, solo_partition
@@ -95,24 +96,25 @@ class DispatchPolicy:
         policy so the simulator can pass its snapshot unconditionally;
         the base planner contract ``plan(queue)`` is context-blind, so it
         is *not* forwarded — the RL delegate consumes it."""
-        before = (self.stats.unprofiled_jobs, self.stats.planned_jobs)
-        cls = type(self)
-        if cls.dispatch is not DispatchPolicy.dispatch:
-            # legacy subclass extension point: honor the override (its
-            # super() chain lands back in the shim below)
-            sched = self.dispatch(submissions, context=context)
-            pls = to_placements(sched)
-        elif cls.placements is not DispatchPolicy.placements:
-            self._last_schedule = None
-            pls = self.placements(submissions, context=context)
-            sched = self._last_schedule
-        else:
-            sched = self._plan_schedule(submissions, context=context)
-            pls = to_placements(sched)
-        return DispatchDecision(
-            schedule=sched, placements=tuple(pls),
-            first_sight=self.stats.unprofiled_jobs - before[0],
-            planned=self.stats.planned_jobs - before[1])
+        with spans.span("repro.policy.decide"):
+            before = (self.stats.unprofiled_jobs, self.stats.planned_jobs)
+            cls = type(self)
+            if cls.dispatch is not DispatchPolicy.dispatch:
+                # legacy subclass extension point: honor the override (its
+                # super() chain lands back in the shim below)
+                sched = self.dispatch(submissions, context=context)
+                pls = to_placements(sched)
+            elif cls.placements is not DispatchPolicy.placements:
+                self._last_schedule = None
+                pls = self.placements(submissions, context=context)
+                sched = self._last_schedule
+            else:
+                sched = self._plan_schedule(submissions, context=context)
+                pls = to_placements(sched)
+            return DispatchDecision(
+                schedule=sched, placements=tuple(pls),
+                first_sight=self.stats.unprofiled_jobs - before[0],
+                planned=self.stats.planned_jobs - before[1])
 
     def _plan_schedule(self, submissions, context=None) -> Schedule:
         """The shared protocol body (the RL policy swaps in its delegate)."""

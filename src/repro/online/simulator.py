@@ -100,6 +100,7 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
+from repro import spans
 from repro.core.env import DispatchContext
 from repro.core.partition import N_UNITS, VALID_WIDTHS, find_offsets, solo_partition
 from repro.core.perfmodel import CoRunResult, corun
@@ -621,37 +622,44 @@ class ClusterSimulator:
     # ------------------------------------------------------------------ run
 
     def run(self, trace: list[Arrival]) -> SimResult:
+        with spans.span("repro.sim.run"):
+            return self._run(trace)
+
+    def _run(self, trace: list[Arrival]) -> SimResult:
         cfg = self.config
+        span = spans.span
         res = SimResult(policy=getattr(self.policy, "name", "policy"),
                         window=cfg.window, jobs=[], mode=cfg.mode,
                         slice_busy_s=[0.0] * cfg.total_units,
                         pods=cfg.pods, router=cfg.router)
         heap: list[tuple[float, int, int, object]] = []
         seq = 0
-        # heap/pending carry the sorted-trace *index*, not the Arrival:
-        # traces may legitimately reuse one Arrival object (batch
-        # submissions), and identity-keyed records would alias
-        order = sorted(trace, key=lambda a: a.t)
-        records = [JobRecord(binary=a.binary, name=a.profile.name,
-                             arrival=a.t, solo_time=a.profile.solo_time(),
-                             idx=i, job_class=a.profile.job_class)
-                   for i, a in enumerate(order)]
-        res.jobs = list(records)
-        # live references: tick callbacks (drift-triggered retraining) read
-        # the in-progress result/trace through live_result/live_arrivals
-        self._live_res, self._live_order = res, order
 
         def push(t, kind, payload):
             nonlocal seq
             heapq.heappush(heap, (t, kind, seq, payload))
             seq += 1
 
-        for i, a in enumerate(order):
-            push(a.t, _ARRIVE, i)
-        if cfg.tick_interval_s and trace:
-            push(cfg.tick_interval_s, _TICK, None)
-
-        self._reset_pods()
+        with span("repro.sim.prepare"):
+            # heap/pending carry the sorted-trace *index*, not the Arrival:
+            # traces may legitimately reuse one Arrival object (batch
+            # submissions), and identity-keyed records would alias
+            order = sorted(trace, key=lambda a: a.t)
+            records = [JobRecord(binary=a.binary, name=a.profile.name,
+                                 arrival=a.t,
+                                 solo_time=a.profile.solo_time(),
+                                 idx=i, job_class=a.profile.job_class)
+                       for i, a in enumerate(order)]
+            res.jobs = list(records)
+            # live references: tick callbacks (drift-triggered retraining)
+            # read the in-progress result/trace through
+            # live_result/live_arrivals
+            self._live_res, self._live_order = res, order
+            for i, a in enumerate(order):
+                push(a.t, _ARRIVE, i)
+            if cfg.tick_interval_s and trace:
+                push(cfg.tick_interval_s, _TICK, None)
+            self._reset_pods()
         n_pods = cfg.n_pods
 
         def work_left():
@@ -687,7 +695,8 @@ class ClusterSimulator:
                 # cluster), and stop rescheduling once the trace is served
                 if heap or work_left():
                     if self.on_tick is not None:
-                        self.on_tick(now, self)
+                        with span("repro.sim.tick"):
+                            self.on_tick(now, self)
                     res.ticks += 1
                     if tel is not None:
                         tel.on_tick(now)
@@ -930,25 +939,27 @@ class ClusterSimulator:
                 for j in pl.group]
 
     def _form_window(self, now, pod: _Pod, res, order, records) -> None:
-        head = [pod.pending.popleft()
-                for _ in range(min(self.window, len(pod.pending)))]
-        subs = [(order[i].binary, order[i].profile) for i in head]
-        ctx = self._dispatch_context(now, pod, head, order)
-        decision = self._decide(subs, ctx)
-        by_name: dict[str, deque] = defaultdict(deque)
-        for i in head:
-            by_name[order[i].profile.name].append(records[i])
-        for pl in decision.placements:
-            for fitted in self._fit_to_pod(pl, pod, res, now):
-                recs = [by_name[j.name].popleft() for j in fitted.group]
-                pod.ready.append(_Run(fitted.group, fitted.partition, recs,
-                                      corun(fitted.group, fitted.partition),
-                                      window_id=res.dispatches))
-        leftover = [n for n, d in by_name.items() if d]
-        assert not leftover, f"policy dropped submissions: {leftover}"
-        res.dispatches += 1
-        if self.telemetry is not None:
-            self.telemetry.on_window(now, pod.idx, head, len(pod.pending))
+        with spans.span("repro.sim.window"):
+            head = [pod.pending.popleft()
+                    for _ in range(min(self.window, len(pod.pending)))]
+            subs = [(order[i].binary, order[i].profile) for i in head]
+            ctx = self._dispatch_context(now, pod, head, order)
+            decision = self._decide(subs, ctx)
+            by_name: dict[str, deque] = defaultdict(deque)
+            for i in head:
+                by_name[order[i].profile.name].append(records[i])
+            for pl in decision.placements:
+                for fitted in self._fit_to_pod(pl, pod, res, now):
+                    recs = [by_name[j.name].popleft() for j in fitted.group]
+                    pod.ready.append(_Run(
+                        fitted.group, fitted.partition, recs,
+                        corun(fitted.group, fitted.partition),
+                        window_id=res.dispatches))
+            leftover = [n for n, d in by_name.items() if d]
+            assert not leftover, f"policy dropped submissions: {leftover}"
+            res.dispatches += 1
+            if self.telemetry is not None:
+                self.telemetry.on_window(now, pod.idx, head, len(pod.pending))
 
     def _backfill_scan(self, now, pod: _Pod, res, push) -> bool:
         """EASY backfill: later dispatched groups may start now iff they fit

@@ -46,16 +46,21 @@ consumed by ``OnlineRetrainer(trigger="drift")``.  Per-interval
 time-series come from ``SimResult.timeseries()`` (post-hoc, no recorder
 needed).
 
-:class:`PhaseTimer` is the small wall-clock helper behind
-``benchmarks/online_sim.py --profile``.
+Wall-clock spans
+----------------
+:mod:`repro.spans` (re-exported here as ``spans``) times the live
+dispatch path itself: simulator run, window, ``decide``, the RL episode,
+its environment steps and guard, and each agent round trip.  It is off by
+default; ``benchmarks/online_sim.py --profile`` switches it on.
 """
 from __future__ import annotations
 
 import bisect
 import json
 import math
-import time
 from dataclasses import dataclass, field
+
+from repro import spans  # noqa: F401  (re-exported)
 
 # Shared fixed wait-histogram bucket upper edges (seconds).  The heap's
 # Histogram and the vectorized engine's MetricsState use the same edges,
@@ -537,38 +542,3 @@ class DriftMonitor:
         """Reset the EMA baselines at the next observation (call after a
         retraining cycle: the refreshed agent defines the new normal)."""
         self._pending_rebase = True
-
-
-# ---------------------------------------------------------------------------
-# Wall-clock phase profiling (benchmarks --profile)
-# ---------------------------------------------------------------------------
-
-
-class PhaseTimer:
-    """Accumulate wall time per named phase; ``as_dict`` is JSON-able."""
-
-    def __init__(self):
-        self.totals: dict[str, float] = {}
-
-    class _Span:
-        def __init__(self, timer, name):
-            self.timer, self.name = timer, name
-
-        def __enter__(self):
-            self.t0 = time.perf_counter()
-            return self
-
-        def __exit__(self, *exc):
-            self.timer.totals[self.name] = (
-                self.timer.totals.get(self.name, 0.0)
-                + time.perf_counter() - self.t0)
-            return False
-
-    def phase(self, name: str) -> "PhaseTimer._Span":
-        return PhaseTimer._Span(self, name)
-
-    def add(self, name: str, seconds: float) -> None:
-        self.totals[name] = self.totals.get(name, 0.0) + seconds
-
-    def as_dict(self) -> dict[str, float]:
-        return {k: round(v, 6) for k, v in sorted(self.totals.items())}
