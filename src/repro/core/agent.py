@@ -2,9 +2,11 @@
 
 Two call surfaces share the same parameters and update rule:
 
-  * ``DQNAgent`` — the stateful single-env agent used by ``RLScheduler`` and
-    the scalar training loop.  Greedy (evaluation) calls do **not** advance
-    ``env_steps``, so evaluation frequency cannot perturb the ε schedule.
+  * ``DQNAgent`` — the stateful single-env agent used by ``RLScheduler``
+    (``greedy_episode``: a whole greedy episode in one device call) and
+    the scalar training loop (``act``: one step a call).  Greedy
+    (evaluation) calls do **not** advance ``env_steps``, so evaluation
+    frequency cannot perturb the ε schedule.
   * ``act_batch`` / ``epsilon_at`` — pure functions over (params, key,
     obs, mask) used by the vectorized engine: vmapped ε-greedy selection
     with ``jax.random`` keys and the linear ε schedule computed in-graph,
@@ -23,6 +25,8 @@ from repro import spans
 from repro.core.network import (
     dqn_apply, greedy_q_action, init_dqn, masked_argmax,
 )
+from repro.core.partition import enumerate_partitions
+from repro.core.profiles import FEATURES
 from repro.core.replay import PrioritizedReplayBuffer, ReplayBuffer
 
 
@@ -149,6 +153,59 @@ def _greedy_action(params, obs, mask):
     return greedy_q_action(params, obs, mask)
 
 
+@functools.partial(jax.jit, static_argnames=("window", "c_max", "obs_context"))
+def _greedy_episode(params, packed, window: int, c_max: int,
+                    obs_context: bool):
+    """One whole greedy co-scheduling episode on the device.
+
+    ``packed`` is the window on the host's side, flat f32: the ``(W, F)``
+    profile features, the ``(W,)`` slot validity and, under
+    ``obs_context``, the context block.  Each of the ``2 W`` scan steps
+    (selects plus closes bound any episode) builds the observation and
+    mask as ``VecCoScheduleEnv._obs``/``_mask`` do, picks the action with
+    ``greedy_q_action`` and applies it; steps after done, or on an invalid
+    action, change nothing.  Returns ``(2 W + 2,)`` i32: the actions taken
+    (``-1`` where none), their count and the done flag.
+    """
+    W, C, F = window, c_max, len(FEATURES)
+    i32, f32 = jnp.int32, jnp.float32
+    arity = jnp.asarray([p.arity for p in enumerate_partitions(C)], i32)
+    features = packed[:W * F].reshape(W, F)
+    valid = packed[W * F:W * F + W] > 0.5
+    ctx = packed[W * F + W:]
+    w_rng, c_rng = jnp.arange(W, dtype=i32), jnp.arange(C, dtype=i32)
+
+    def step(carry, _):
+        sched, gidx, gsize = carry
+        member = jnp.any((gidx[None, :] == w_rng[:, None])
+                         & (c_rng < gsize)[None, :], axis=1)
+        avail = valid & ~sched & ~member
+        progress = gsize.astype(f32) / max(1, C)
+        flags = jnp.stack([avail.astype(f32), member.astype(f32),
+                           (sched & valid).astype(f32), (~valid).astype(f32),
+                           jnp.where(valid, progress, 0.0)], axis=1)
+        obs = jnp.concatenate([features, flags], axis=1).reshape(-1)
+        if obs_context:
+            obs = jnp.concatenate([obs, ctx])
+        mask = jnp.concatenate([avail & (gsize < C),
+                                (gsize >= 1) & (arity == gsize)])
+        done = jnp.all(sched | ~valid) & (gsize == 0)
+        act = greedy_q_action(params, obs, mask)
+        ok = ~done & mask[act]
+        do_sel, do_close = ok & (act < W), ok & (act >= W)
+        sched = sched | (member & do_close)
+        gidx = gidx.at[jnp.where(do_sel, gsize, C)].set(act, mode="drop")
+        gidx = jnp.where(do_close, jnp.full(C, -1, i32), gidx)
+        gsize = jnp.where(do_close, 0, gsize + do_sel.astype(i32))
+        return (sched, gidx, gsize), jnp.where(ok, act, -1)
+
+    init = (jnp.zeros(W, bool), jnp.full(C, -1, i32), jnp.int32(0))
+    (sched, _, gsize), acts = jax.lax.scan(step, init, None, length=2 * W)
+    done = jnp.all(sched | ~valid) & (gsize == 0)
+    return jnp.concatenate([acts, jnp.sum(acts >= 0, dtype=i32)[None],
+                            done.astype(i32)[None]])
+
+
 def epsilon_at(cfg: DQNConfig, env_steps):
     """Linear ε schedule as a pure function of the env-step count.
 
@@ -272,3 +329,19 @@ class DQNAgent:
         if self.updates % self.cfg.target_sync == 0:
             self.target_params = jax.tree.map(jnp.copy, self.params)
         return float(loss)
+
+    def greedy_episode(self, packed: np.ndarray, window: int, c_max: int,
+                       obs_context: bool) -> np.ndarray:
+        """A whole greedy episode in one round trip (see
+        :func:`_greedy_episode` for ``packed`` and the result): one
+        transfer in, one launch, one fetch.  The parameters are an argument
+        of the compiled program, so a swap to same-shaped ones reuses it."""
+        span = spans.span
+        with span("repro.agent.episode"):
+            with span("repro.agent.episode.put"):
+                x = jax.device_put(packed)
+            with span("repro.agent.episode.launch"):
+                out = _greedy_episode(self.params, x, window=window,
+                                      c_max=c_max, obs_context=obs_context)
+            with span("repro.agent.episode.fetch"):
+                return np.asarray(out)

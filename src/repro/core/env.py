@@ -137,16 +137,27 @@ def zero_context(window: int) -> ObsContext:
     )
 
 
+def context_block(ctx: DispatchContext, window: int) -> np.ndarray:
+    """A simulator snapshot as the observation's flat context block, on the
+    host: ``(N_UNITS + window + 1,)`` f32 — busy mask, per-slot ages,
+    depth, in that order."""
+    out = np.zeros((N_UNITS + window + 1,), np.float32)
+    busy = [0.0 if f else 1.0 for f in ctx.free_units]
+    assert len(busy) == N_UNITS, ctx.free_units
+    out[:N_UNITS] = busy
+    for i, a in enumerate(ctx.ages_s[:window]):
+        out[N_UNITS + i] = age_feature(a)
+    out[-1] = depth_feature(ctx.queue_depth, window)
+    return out
+
+
 def dispatch_obs_context(ctx: DispatchContext, window: int) -> ObsContext:
     """Normalize a simulator snapshot into the observation's context block."""
-    busy = np.asarray([0.0 if f else 1.0 for f in ctx.free_units], np.float32)
-    assert busy.shape == (N_UNITS,), ctx.free_units
-    ages = np.zeros((window,), np.float32)
-    for i, a in enumerate(ctx.ages_s[:window]):
-        ages[i] = age_feature(a)
+    block = context_block(ctx, window)
     return ObsContext(
-        busy_units=jnp.asarray(busy), ages=jnp.asarray(ages),
-        queue_depth=jnp.float32(depth_feature(ctx.queue_depth, window)),
+        busy_units=jnp.asarray(block[:N_UNITS]),
+        ages=jnp.asarray(block[N_UNITS:-1]),
+        queue_depth=jnp.float32(block[-1]),
     )
 
 
@@ -470,10 +481,7 @@ class CoScheduleEnv:
                                                   np.float32)])
         # one normalization implementation: the same conversion the
         # vectorized serve path uses (busy, ages, depth — in that order)
-        oc = dispatch_obs_context(self._ctx, W)
-        return np.concatenate([flat, np.asarray(oc.busy_units),
-                               np.asarray(oc.ages),
-                               np.asarray(oc.queue_depth)[None]])
+        return np.concatenate([flat, context_block(self._ctx, W)])
 
     # ------------------------------------------------------------- rewards
     def _close_reward(self, group: list[JobProfile], partition: Partition) -> float:

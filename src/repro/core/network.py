@@ -55,8 +55,9 @@ def greedy_q_action(params: dict, obs: jnp.ndarray, mask: jnp.ndarray) -> jnp.nd
     """Greedy fit-masked action for one observation: () i32.
 
     The single action-selection implementation shared by
-    ``DQNAgent.act(greedy=True)`` (the heap serving path) and the
-    vectorized engine's in-graph policy seam — ties break to the first
+    ``DQNAgent.act(greedy=True)``, the heap serving path's in-graph
+    episode (``DQNAgent.greedy_episode``) and the vectorized engine's
+    in-graph policy seam — ties break to the first
     maximal index on both, so the two paths pick identical actions on
     identical observations (the property the parity fuzzer pins).
     """

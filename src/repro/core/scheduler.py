@@ -1,13 +1,14 @@
 """Online phase (paper §IV-B): trained agent -> (L_JS, L_R) for a queue.
 
-The agent runs greedily (ε = 0) on the stateful reference env — greedy
-calls do not advance the agent's ε-decay schedule, so scheduling/evaluation
-frequency never perturbs training exploration. The §IV-A constraint
-``CoRunTime <= SoloRunTime`` is then *enforced by construction*: any group
-whose predicted co-run loses to time sharing is split back into solo runs
-(the paper's constraint-1 guard).  Jobs without a profile in the repository
-are excluded from co-scheduling and executed solo while being profiled
-(paper's online protocol).
+The agent runs greedily (ε = 0), the whole episode as one device call
+whose steps mirror the stateful reference env (``CoScheduleEnv``) —
+greedy episodes do not advance the agent's ε-decay schedule, so
+scheduling/evaluation frequency never perturbs training exploration.
+The §IV-A constraint ``CoRunTime <= SoloRunTime`` is then *enforced by
+construction*: any group whose predicted co-run loses to time sharing is
+split back into solo runs (the paper's constraint-1 guard).  Jobs without
+a profile in the repository are excluded from co-scheduling and executed
+solo while being profiled (paper's online protocol).
 
 Two shared pieces sit between any planner and the cluster simulator:
 
@@ -31,13 +32,17 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 
+import numpy as np
+
 from repro import spans
 from repro.core.agent import DQNAgent
-from repro.core.env import CoScheduleEnv, DispatchContext, EnvConfig
-from repro.core.partition import Partition, Slice, slice_label, solo_partition
+from repro.core.env import DispatchContext, EnvConfig, context_block, context_dim
+from repro.core.partition import (
+    Partition, Slice, enumerate_partitions, slice_label, solo_partition,
+)
 from repro.core.perfmodel import corun_time, solo_run_time
 from repro.core.problem import Schedule
-from repro.core.profiles import JobProfile, ProfileRepository
+from repro.core.profiles import FEATURES, JobProfile, ProfileRepository
 
 
 @dataclass
@@ -173,27 +178,54 @@ class RLScheduler:
         # silently sever the caller's handle to the shared profile store
         self.repository = repository if repository is not None else ProfileRepository()
         self.stats = SchedulerStats()
+        self._partitions = enumerate_partitions(self.env_cfg.c_max)
 
     def schedule(self, queue: list[JobProfile],
                  context: DispatchContext | None = None) -> Schedule:
         """Greedy episode over ``queue``; ``context`` is the dispatch-time
         cluster snapshot an ``obs_context`` environment folds into the
-        observation (ignored — zero block — otherwise)."""
-        span = spans.span
-        with span("repro.sched.episode"):
-            env = CoScheduleEnv(self.env_cfg)
-            with span("repro.sched.env"):
-                state, mask = env.reset(queue, context)
-            guard = 0
-            while not env.done:
-                action = self.agent.act(state, mask, greedy=True)
-                with span("repro.sched.env"):
-                    state, _, _, mask, _ = env.step(action)
-                guard += 1
-                assert guard < 10 * self.env_cfg.window, \
-                    "scheduler failed to terminate"
-            spans.count("repro.sched.steps", guard)
-            return self._enforce_constraints(env.schedule)
+        observation (ignored — zero block — otherwise).
+
+        The episode runs on the device in one call
+        (:meth:`DQNAgent.greedy_episode`); the host packs the window's
+        features and replays the returned actions into the Schedule."""
+        cfg = self.env_cfg
+        W = cfg.window
+        with spans.span("repro.sched.episode"):
+            out = self.agent.greedy_episode(self._pack(queue, context), W,
+                                            cfg.c_max, cfg.obs_context)
+            n = int(out[-2])
+            if not out[-1]:
+                raise RuntimeError("scheduler failed to terminate")
+            spans.count("repro.sched.steps", n)
+            sched = Schedule()
+            group: list[JobProfile] = []
+            for a in out[:n].tolist():
+                if a < W:
+                    group.append(queue[a])
+                else:
+                    sched.add(group, self._partitions[a - W])
+                    group = []
+            return self._enforce_constraints(sched)
+
+    def _pack(self, queue: list[JobProfile],
+              context: DispatchContext | None) -> np.ndarray:
+        """The episode's host input, flat f32: ``(W, F)`` features (zero
+        rows pad), ``(W,)`` validity, then the context block under
+        ``obs_context`` (zero without a snapshot) — what
+        ``CoScheduleEnv`` would observe at reset."""
+        cfg = self.env_cfg
+        W, F = cfg.window, len(FEATURES)
+        assert len(queue) <= W, (len(queue), W)
+        out = np.zeros((W * F + W + context_dim(cfg),), np.float32)
+        out[:len(queue) * F] = np.asarray(
+            [j.features() for j in queue], np.float32).reshape(-1)
+        out[W * F:W * F + len(queue)] = 1.0
+        if cfg.obs_context and context is not None:
+            assert len(context.ages_s) == len(queue), \
+                (len(context.ages_s), len(queue))
+            out[W * F + W:] = context_block(context, W)
+        return out
 
     def schedule_submissions(self, submissions: list[tuple[str, JobProfile | None]],
                              context: DispatchContext | None = None) -> Schedule:

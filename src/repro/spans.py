@@ -2,9 +2,11 @@
 
 The heap serving loop marks where its time goes with ``span(name)``:
 ``ClusterSimulator.run`` -> ``_form_window`` -> ``DispatchPolicy.decide``
--> ``RLScheduler.schedule`` (environment steps, the co-run guard) ->
-``DQNAgent.act`` (observation to the device, launch, action back).  The
-names are listed in ``docs/observability.md``; all start with ``repro.``.
+-> ``RLScheduler.schedule`` (the episode, the co-run guard) ->
+``DQNAgent.greedy_episode`` (the window to the device, launch, actions
+back).  The scalar training loop's ``DQNAgent.act`` marks its round trip
+the same way.  The names are listed in ``docs/observability.md``; all
+start with ``repro.``.
 
 The recorder is off by default.  Off, :func:`span` returns one shared
 no-op context manager: no clock read, no allocation, no JAX call.
@@ -19,7 +21,7 @@ trace shows it on the host plane, on the clock of the device's operations.
 
     spans.enable()
     ClusterSimulator(policy, cfg).run(trace)
-    spans.summary()["repro.agent.act"]   # count, total_s, self_s, median_us
+    spans.summary()["repro.agent.episode"]   # count, total_s, self_s, median_us
     spans.reset()
 
 Spans are kept in memory until :func:`reset`, and are recorded from the

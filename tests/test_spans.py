@@ -13,7 +13,7 @@ import jax
 import pytest
 
 from repro import spans
-from repro.core import DQNAgent, DQNConfig, EnvConfig, make_zoo
+from repro.core import DQNAgent, DQNConfig, EnvConfig, RLScheduler, make_zoo
 from repro.core.env import CoScheduleEnv
 from repro.online import ClusterSimulator, RLDispatchPolicy, poisson_trace
 
@@ -145,11 +145,14 @@ def test_spans_observe_and_never_steer():
     assert summ["repro.policy.decide"]["count"] == on.dispatches
     episodes = summ["repro.sched.episode"]["count"]
     assert summ["repro.sched.guard"]["count"] == episodes
+    # one device round trip per episode, and no scalar env or act on the
+    # serving path
+    for name in ("repro.agent.episode", "repro.agent.episode.put",
+                 "repro.agent.episode.launch", "repro.agent.episode.fetch"):
+        assert summ[name]["count"] == episodes
     steps = spans.counters()["repro.sched.steps"]
-    assert len(steps) == episodes
-    assert sum(steps) == summ["repro.agent.act"]["count"]
-    # one reset per episode and one step per action
-    assert summ["repro.sched.env"]["count"] == episodes + sum(steps)
+    assert len(steps) == episodes and all(n >= 2 for n in steps)
+    assert "repro.agent.act" not in summ and "repro.sched.env" not in summ
     assert all(s.name.startswith("repro.") for s in spans.records())
     (run,) = [s for s in spans.records() if s.name == "repro.sim.run"]
     assert {s.root for s in spans.records()} == {run.id}
@@ -175,6 +178,28 @@ def test_act_spans_nest_put_launch_fetch():
     assert act.start_ns <= kids[0].start_ns
     assert all(a.end_ns <= b.start_ns for a, b in zip(kids, kids[1:]))
     assert kids[-1].end_ns <= act.end_ns
+
+
+def test_episode_spans_nest_put_launch_fetch():
+    agent = _agent()
+    sched = RLScheduler(agent, ENV_CFG)
+    packed = sched._pack(list(ZOO[:4]), None)
+    want = agent.greedy_episode(packed, ENV_CFG.window, ENV_CFG.c_max, False)
+    spans.enable()
+    got = agent.greedy_episode(packed, ENV_CFG.window, ENV_CFG.c_max, False)
+    spans.disable()
+    assert (got == want).all()
+    recs = spans.records()
+    (ep,) = [s for s in recs if s.name == "repro.agent.episode"]
+    kids = sorted((s for s in recs if s.parent == ep.id),
+                  key=lambda s: s.start_ns)
+    assert [s.name for s in kids] == ["repro.agent.episode.put",
+                                      "repro.agent.episode.launch",
+                                      "repro.agent.episode.fetch"]
+    assert ep.parent is None and all(s.root == ep.id for s in kids)
+    assert ep.start_ns <= kids[0].start_ns
+    assert all(a.end_ns <= b.start_ns for a, b in zip(kids, kids[1:]))
+    assert kids[-1].end_ns <= ep.end_ns
 
 
 def test_spans_land_on_the_profiler_host_plane(tmp_path):

@@ -121,6 +121,26 @@ def test_rl_fleet_compiles(one_chip):
     _compiled_ok(fleet._runp.lower(*_sds(args, one_chip)).compile())
 
 
+@pytest.mark.parametrize("obs_context", [False, True])
+def test_heap_episode_compiles_to_f32_vector_products(one_chip, obs_context):
+    """The heap serving path's episode program: its single-observation
+    products lower to f32 vector code like ``_greedy_action``'s, never to
+    MXU convolutions, which at default precision take bfloat16 passes."""
+    from repro.core.agent import _greedy_episode
+    from repro.core.env import context_dim
+    from repro.core.profiles import FEATURES
+
+    cfg = EnvConfig(window=8, c_max=4, obs_context=obs_context)
+    env = CoScheduleEnv(cfg)
+    agent = DQNAgent(env.state_dim, env.n_actions, seed=0)
+    W = cfg.window
+    packed = np.zeros((W * len(FEATURES) + W + context_dim(cfg),), np.float32)
+    compiled = _compiled_ok(_greedy_episode.lower(
+        *_sds((agent.params, packed), one_chip), window=W, c_max=cfg.c_max,
+        obs_context=obs_context).compile())
+    assert "convolution" not in compiled.as_text()
+
+
 def test_train_agent_segment_compiles(one_chip):
     # the default TrainConfig's cadence, as train_agent derives it: 16 envs
     # and one update per 16 transitions -> one update per scan step
