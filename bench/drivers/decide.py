@@ -2,10 +2,12 @@
 serving through ``RLDispatchPolicy.decide``.
 
 One unit serves one trace of the pool on a fresh cluster (empty profile
-repository).  Every ``decide()`` call and every ``agent.act`` call is
-timed on the host clock; ``decide_p95_ms`` is the 95th percentile of all
-``decide()`` calls of the window.  The loop is closed: the heap advances
-simulated time between decisions, so a call's time is its service time.
+repository).  Every ``decide()`` call and every ``agent.greedy_episode``
+call (the window's one device round trip, where the window holds a
+profiled job) is timed on the host clock; ``decide_p95_ms`` is the 95th
+percentile of all ``decide()`` calls of the window.  The loop is closed:
+the heap advances simulated time between decisions, so a call's time is
+its service time.
 """
 from __future__ import annotations
 
@@ -41,8 +43,8 @@ def run(ctx) -> dict:
         base = inputs.program_policy()
         agent, env_cfg, cfg = base.agent, base.scheduler.env_cfg, \
             inputs.sim_config()
-        act = _Timed(agent.act, spans, "bench.act")
-        agent.act = act
+        episode = _Timed(agent.greedy_episode, spans, "bench.episode")
+        agent.greedy_episode = episode
         decide_s: list[float] = []
 
         def serve(trace):
@@ -55,7 +57,7 @@ def run(ctx) -> dict:
 
         serve(pool[0][:64])
         decide_s.clear()
-        act.samples.clear()
+        episode.samples.clear()
 
     served: dict[int, object] = {}
     arrivals = units = 0
@@ -82,7 +84,9 @@ def run(ctx) -> dict:
             "counters": {"units": units, "arrivals": arrivals,
                          "decide_calls": len(ms),
                          "decide_p50_ms": float(np.median(ms)),
-                         "act_calls": len(act.samples),
-                         "act_p50_us": float(np.median(act.samples)) * 1e6,
+                         "episode_calls": len(episode.samples),
+                         "episode_p50_us": (
+                             float(np.median(episode.samples)) * 1e6
+                             if episode.samples else None),
                          "compared_traces": sorted(served)},
             "attempted": arrivals, "failed": failed, **verdict}

@@ -35,7 +35,7 @@ def run(ctx) -> dict:
         sim = VectorizedFleetSimulator(inputs.program_policy(),
                                        inputs.sim_config(),
                                        capacity=ctx.spec["lane_capacity"])
-        sim.run(_warm_trace(pool[0], inputs.pool[0][1]))
+        sim.run(_warm_trace(pool[0], inputs.pool[0].picks))
 
     served: dict[int, object] = {}
     arrivals = units = 0

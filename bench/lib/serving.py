@@ -8,6 +8,7 @@ the reference except the decisions it is judged on.
 """
 from __future__ import annotations
 
+import importlib
 import json
 from pathlib import Path
 
@@ -29,40 +30,67 @@ OPERANDS = {"float32": np.float32, "bfloat16": ml_dtypes.bfloat16}
 CLOCKS = {"float64": f64_clock, "float32": f32_clock}
 
 
+def load_planner(name: str):
+    """The reference planner class ``Planner`` of ``bench/reference/<name>.py``
+    (constructed as :class:`RLPlanner` is)."""
+    return importlib.import_module(f"bench.reference.{name}").Planner
+
+
 class Inputs:
     """A cell's frozen inputs and its trace pool for one ``--seed``."""
 
     def __init__(self, config: dict, traffic_spec: dict, seed: int):
         self.config = config
         self.zoo = traffic.load_zoo()
-        self.ref_jobs = jobs_from_snapshot(self.zoo)
         capacity = sum(config["pods"]) / N_UNITS
         self.pool = traffic.make_pool(traffic_spec, self.zoo, seed, capacity)
         agent = config.get("agent")
         self.params = (dict(np.load(BENCH / agent["params"]))
                        if agent else None)
+        self._ref_jobs: dict[tuple[int, int], object] = {}
+
+    def _arrivals(self, k: int):
+        """Trace ``k`` as ``(time, zoo row as submitted, (pick, width))``."""
+        times, picks, units = self.pool[k]
+        return [(float(t), traffic.variant(self.zoo[p], int(w)),
+                 (int(p), int(w)))
+                for t, p, w in zip(times, picks, units)]
 
     # ------------------------------------------------------------ program
 
     def program_pool(self):
         """The pool as the program's ``Arrival`` lists; one ``JobProfile``
-        object per zoo entry, shared by its arrivals."""
+        object per zoo entry and requested width, shared by its arrivals."""
         from repro.core.profiles import JobProfile
         from repro.online.simulator import Arrival
 
         keys = ("name", "arch", "shape", "steps", "flops_total",
                 "bytes_total", "coll_bytes_chip_pod", "n_coll_step",
                 "serial_s")
-        profs = [JobProfile(**{k: r[k] for k in keys}, meta=dict(r["meta"]))
-                 for r in self.zoo]
-        bins = [traffic.binary(r) for r in self.zoo]
-        return [[Arrival(t=float(t), binary=bins[p], profile=profs[p])
-                 for t, p in zip(times, picks)]
-                for times, picks in self.pool]
+        profs: dict[tuple[int, int], JobProfile] = {}
+
+        def profile(row, key):
+            if key not in profs:
+                profs[key] = JobProfile(**{k: row[k] for k in keys},
+                                        meta=dict(row["meta"]))
+            return profs[key]
+
+        return [[Arrival(t=t, binary=traffic.binary(row),
+                         profile=profile(row, key))
+                 for t, row, key in self._arrivals(k)]
+                for k in range(len(self.pool))]
+
+    def env_config(self):
+        """The agent's ``EnvConfig``: ``window``, ``c_max`` and
+        ``obs_context`` (default false) of the configuration's agent."""
+        from repro.core import EnvConfig
+
+        a = self.config["agent"]
+        return EnvConfig(window=a["window"], c_max=a["c_max"],
+                         obs_context=a.get("obs_context", False))
 
     def program_policy(self):
         """The configured policy, built from the program's classes."""
-        from repro.core import EnvConfig
         from repro.online import RLDispatchPolicy, TimeSharingPolicy
 
         if self.config["policy"] == "time_sharing":
@@ -71,8 +99,7 @@ class Inputs:
         from repro.core.agent import DQNAgent
         from repro.core.env import CoScheduleEnv
 
-        a = self.config["agent"]
-        env_cfg = EnvConfig(window=a["window"], c_max=a["c_max"])
+        env_cfg = self.env_config()
         env = CoScheduleEnv(env_cfg)
         agent = DQNAgent(env.state_dim, env.n_actions, seed=0)
         agent.params = {k: jnp.asarray(v, jnp.float32)
@@ -89,17 +116,26 @@ class Inputs:
     # ---------------------------------------------------------- reference
 
     def reference_trace(self, k: int):
-        times, picks = self.pool[k]
-        return [(float(t), traffic.binary(self.zoo[p]), self.ref_jobs[p])
-                for t, p in zip(times, picks)]
+        """Trace ``k`` as the reference's ``(t, binary, Job)`` triples; one
+        ``Job`` per zoo entry and requested width."""
+        out = []
+        for t, row, key in self._arrivals(k):
+            if key not in self._ref_jobs:
+                self._ref_jobs[key] = jobs_from_snapshot([row])[0]
+            out.append((t, traffic.binary(row), self._ref_jobs[key]))
+        return out
 
     def reference(self, clock: str, operands: str, tie_tol: float):
+        """The reference fleet; an RL policy plans with the configuration's
+        agent ``planner`` module, or :class:`RLPlanner` where none is
+        named."""
         c = self.config
         planner = None
         if c["policy"] == "rl":
             a = c["agent"]
-            planner = RLPlanner(ReferenceAgent(self.params, OPERANDS[operands]),
-                                a["window"], a["c_max"], tie_tol)
+            cls = load_planner(a["planner"]) if "planner" in a else RLPlanner
+            planner = cls(ReferenceAgent(self.params, OPERANDS[operands]),
+                          a["window"], a["c_max"], tie_tol)
         return ReferenceFleet(tuple(c["pods"]), c["window"], planner,
                               CLOCKS[clock])
 

@@ -1,26 +1,44 @@
-"""The benchmark's traffic generator: Poisson arrivals over the frozen zoo.
+"""The benchmark's traffic: arrival processes over the frozen zoo.
 
-A frozen copy of the Poisson arrival process and the paper's §V-A2 class
-mixes ("balanced": CI/MI/US equally likely; "ci"/"mi"/"us": 50% the named
+A traffic file (``bench/traffic/<name>.json``) names its arrival
+``process``; the process is the module ``bench/lib/processes/<process>.py``,
+found by name, whose ``trace(traffic, zoo, seed, capacity)`` turns the
+file's parameters, the zoo, one trace seed and the fleet's capacity (in
+full-pod equivalents) into one :class:`Trace`.  Every traffic file also
+gives ``arrivals`` per trace and ``pool`` (traces made per run, served in
+turn); the rest of its keys are the process's own.
+
+Job solo times and classes come from the zoo snapshot
+(``bench/data/zoo.json``), not from the program's performance model, and
+the class mixes are frozen copies of the paper's §V-A2 recipes
+("balanced": CI/MI/US equally likely; "ci"/"mi"/"us": 50% the named
 class, 25% each other), so a traffic mix keeps its arrivals when the
-program's own generators change.  Job solo times and classes come from
-the zoo snapshot (``bench/data/zoo.json``), not from the program's
-performance model.
-
-A traffic file (``bench/traffic/<name>.json``) gives ``process``
-("poisson"), ``load`` (offered solo work per full-pod equivalent of the
-fleet), ``mix``, ``arrivals`` per trace and ``pool`` (traces made per
-run, served in turn).
+program's own generators change.
 """
 from __future__ import annotations
 
+import importlib.util
 import json
 from pathlib import Path
+from typing import NamedTuple
 
 import numpy as np
 
-ZOO = Path(__file__).resolve().parents[1] / "data" / "zoo.json"
+from bench.reference.model import N_UNITS
+
+BENCH = Path(__file__).resolve().parents[1]
+ZOO = BENCH / "data" / "zoo.json"
+PROCESSES = BENCH / "lib" / "processes"
 _CLASS_ORDER = ("CI", "MI", "US")
+
+
+class Trace(NamedTuple):
+    """One trace: arrival times (float64 s), zoo indices, and the slice
+    width each arrival requests (``N_UNITS`` for a full-pod request)."""
+
+    times: np.ndarray
+    picks: np.ndarray
+    units: np.ndarray
 
 
 def load_zoo() -> list[dict]:
@@ -52,14 +70,15 @@ def rate(zoo: list[dict], load: float, capacity: float) -> float:
     return capacity * load / float(np.mean([j["solo_time_s"] for j in zoo]))
 
 
-def poisson(zoo: list[dict], n: int, load: float, mix: str, seed: int,
-            capacity: float) -> tuple[np.ndarray, np.ndarray]:
-    """Arrival times (s) and zoo indices of one Poisson trace."""
-    rng = np.random.default_rng(seed)
-    times = np.cumsum(rng.exponential(1.0 / rate(zoo, load, capacity),
-                                      size=n))
-    picks = rng.choice(len(zoo), size=n, p=job_probs(zoo, mix))
-    return times, picks
+def variant(row: dict, units: int) -> dict:
+    """The zoo row as it is submitted at ``units`` slice units: the row
+    itself at full width; narrower, a variant named ``<name>@u<units>``
+    with ``meta["units"]``, so the profile repository keeps each width as
+    its own application."""
+    if units >= N_UNITS:
+        return row
+    return dict(row, name=f"{row['name']}@u{units}",
+                meta={**row["meta"], "units": int(units)})
 
 
 def binary(row: dict) -> str:
@@ -72,10 +91,20 @@ def trace_seeds(seed: int, pool: int) -> list[int]:
     return [int(s) for s in ss.generate_state(pool, np.uint32)]
 
 
+def load_process(name: str):
+    """The arrival process module ``bench/lib/processes/<name>.py``."""
+    path = PROCESSES / f"{name}.py"
+    if not path.is_file():
+        raise ValueError(f"unknown arrival process {name!r}")
+    spec = importlib.util.spec_from_file_location(f"bench_process_{name}",
+                                                  path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def make_pool(traffic: dict, zoo: list[dict], seed: int,
-              capacity: float) -> list[tuple[np.ndarray, np.ndarray]]:
-    if traffic["process"] != "poisson":
-        raise ValueError(f"unknown arrival process {traffic['process']!r}")
-    return [poisson(zoo, traffic["arrivals"], traffic["load"],
-                    traffic["mix"], s, capacity)
+              capacity: float) -> list[Trace]:
+    process = load_process(traffic["process"])
+    return [Trace(*process.trace(traffic, zoo, s, capacity))
             for s in trace_seeds(seed, traffic["pool"])]

@@ -19,12 +19,18 @@ the tie tolerance, the reference takes the one whose window schedule the
 program's records show, and reports how far that action lay below its
 best (``tie_gap``): the rounding of a sound forward pass may pick either,
 and a larger gap is a decision the reference would not make.
+
+A planner is called as ``plan(queue, accept, view)`` once a window forms
+with at least one profiled job; ``view`` is the pod as the reference sees
+it then (:class:`View`), for a planner whose agent observes more than the
+window's profiles.  :class:`RLPlanner` ignores it.
 """
 from __future__ import annotations
 
 import heapq
 import zlib
 from collections import defaultdict, deque
+from typing import NamedTuple
 
 import numpy as np
 
@@ -50,6 +56,16 @@ def f32_clock(x: float) -> float:
 
 def f64_clock(x: float) -> float:
     return x
+
+
+class View(NamedTuple):
+    """The pod when a window forms: its free-unit mask, the age of each
+    head submission (seconds since arrival, in window order) and the
+    number of submissions still pending behind the window."""
+
+    free_units: tuple[bool, ...]
+    ages_s: tuple[float, ...]
+    queue_depth: int
 
 
 # ------------------------------------------------------------------- agent
@@ -152,10 +168,12 @@ class RLPlanner:
             ep.in_group = []
         return ep
 
-    def plan(self, queue: list[Job], accept) -> tuple[list, float, bool]:
+    def plan(self, queue: list[Job], accept,
+             view: View | None = None) -> tuple[list, float, bool]:
         """Groups of ``queue`` as ``[(jobs, partition)]`` after the
         co-run-versus-time-sharing guard, the gap of the path taken and
-        whether ``accept`` took it.
+        whether ``accept`` took it.  ``view`` is not used: this agent
+        observes the window's profiles only.
 
         Paths are expanded in order of their largest gap below the best
         action, so the greedy path comes first; among near-ties, the first
@@ -248,7 +266,8 @@ def _width_fit(group: list[Job], p: Partition) -> Partition:
 class ReferenceFleet:
     """Event-driven reference of one hash-routed fleet and policy.
 
-    ``planner`` is None for time sharing, else an :class:`RLPlanner`.
+    ``planner`` is None for time sharing, else an :class:`RLPlanner` or a
+    planner that takes its constructor and its ``plan``.
     ``clock`` rounds every simulated time the reference computes: float64
     is the configuration's clock, float32 the control's."""
 
@@ -342,9 +361,10 @@ class ReferenceFleet:
             if not progress:
                 return
 
-    def _decide(self, head, order, accept):
+    def _decide(self, head, order, accept, view: View):
         """First sight: solo on the full pod while profiled; the rest is
-        planned as one window.  Returns ``[(jobs, partition)]``."""
+        planned as one window, the planner given ``view``.  Returns
+        ``[(jobs, partition)]``."""
         out, queue = [], []
         for i in head:
             _, binary, job = order[i]
@@ -358,8 +378,8 @@ class ReferenceFleet:
             return out
         if self.planner is None:
             return out + [([j], solo_partition()) for j in queue]
-        groups, gap, taken = self.planner.plan(queue,
-                                               lambda g: accept(out + g))
+        groups, gap, taken = self.planner.plan(
+            queue, lambda g: accept(out + g), view)
         self.res["tie_gap"] = max(self.res["tie_gap"], gap)
         if not taken:
             # the program left the reference's path: the records differ
@@ -393,6 +413,9 @@ class ReferenceFleet:
         order = ctx[0]
         head = [pod.pending.popleft()
                 for _ in range(min(self.window, len(pod.pending)))]
+        view = View(tuple(pod.free),
+                    tuple(now - self.clock(order[i][0]) for i in head),
+                    len(pod.pending))
 
         def accept(groups):
             """Whether the program's records show this window schedule:
@@ -418,7 +441,8 @@ class ReferenceFleet:
                     starts.append(f[0][5])
             return starts == sorted(starts)
 
-        fitted = self._placements(pod, self._decide(head, order, accept))
+        fitted = self._placements(pod, self._decide(head, order, accept,
+                                                    view))
         for (g, p), idx in zip(fitted, self._attribute(head, order, fitted)):
             pod.ready.append(_Run(g, p, idx, self.res["dispatches"],
                                   self._corun(g, p)))

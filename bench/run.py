@@ -5,11 +5,27 @@
 The cell is looked up by name in ``BENCHMARK.json``; its configuration
 (``bench/configs/<config>.json``), traffic (``bench/traffic/<traffic>.json``)
 and cell file (``bench/workloads/<cell>.json``, which names the driver in
-``bench/drivers/`` and the check's limits) are found by name, so a cell is
-added with data files only.  With ``--trace 0`` the line carries the
-cell's end-to-end metrics; with ``--trace 1`` its per-layer metrics, each
-read by ``bench/metrics/<metric>.py`` from one profiled unit.  The run
-exits non-zero, printing no result, without enough TPU chips.
+``bench/drivers/`` and the check's limits) are found by name.  With
+``--trace 0`` the line carries the cell's end-to-end metrics; with
+``--trace 1`` its per-layer metrics, each read by
+``bench/metrics/<metric>.py`` from one profiled unit.  The run exits
+non-zero, printing no result, without enough TPU chips.
+
+A new deployment enters as new files and appended entries, with no edit
+to a file that is here:
+
+* a configuration, ``bench/configs/<config>.json`` (pods, window, policy;
+  for an RL policy an ``agent`` block with ``window``, ``c_max``, the
+  frozen ``params`` and, optionally, ``obs_context`` and ``planner``);
+* a traffic file, ``bench/traffic/<traffic>.json``, whose ``process``
+  names ``bench/lib/processes/<process>.py``: a new file only where the
+  arrival shape is new (it may give each arrival a slice width);
+* a reference planner, ``bench/reference/<planner>.py`` with a class
+  ``Planner``, only where the agent sees more than the window's profiles
+  (it is handed the pod's view when the window forms);
+* a cell file, ``bench/workloads/<cell>.json``;
+* entries appended to ``BENCHMARK.json``: the configuration, the cell,
+  and the cell's name in the ``workloads`` of the metrics it reports.
 """
 from __future__ import annotations
 

@@ -97,6 +97,11 @@ def test_cell_files_found_by_name(cell):
     spec = json.loads((BENCH / "workloads" / f"{cell}.json").read_text())
     assert (BENCH / "drivers" / f"{spec['driver']}.py").is_file()
     assert spec["check"]["limits"]["decisions_differing"] == 0
+    cfg = json.loads((BENCH / "configs" / f"{w['config']}.json").read_text())
+    planner = cfg.get("agent", {}).get("planner")
+    if planner is not None:
+        assert planner.isidentifier(), planner
+        assert (BENCH / "reference" / f"{planner}.py").is_file()
 
 
 @pytest.mark.parametrize("cell", sorted(CELLS))
@@ -131,5 +136,6 @@ def test_traffic_files_are_data():
     for w in SPEC["workloads"]:
         t = json.loads((BENCH / "traffic" / f"{w['traffic']}.json")
                        .read_text())
-        assert t["process"] == "poisson"
+        assert NAME.match(t["process"]), t["process"]
+        assert (BENCH / "lib" / "processes" / f"{t['process']}.py").is_file()
         assert t["arrivals"] > 0 and t["pool"] > 0 and t["load"] > 0
