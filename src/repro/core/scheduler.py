@@ -151,22 +151,28 @@ def to_placements(sched: Schedule) -> list[Placement]:
     times still come from :func:`~repro.core.perfmodel.corun` on the fitted
     partition.  Schedules over jobs without width hints pass through
     unchanged (identical objects), which keeps full-pod dispatch
-    bit-compatible."""
-    out: list[Placement] = []
-    for g, p in zip(sched.groups, sched.partitions):
-        new_slices = list(p.slices)
-        changed = False
-        for pos, (si, s, _beta) in enumerate(p.slots):
-            if len(s.shares) != 1:
-                continue
-            req = g[pos].requested_units
-            if req < s.units:
-                new_slices[si] = Slice(req, s.shares)
-                changed = True
-        part = (Partition(tuple(new_slices), slice_label(tuple(new_slices)))
-                if changed else p)
-        out.append(Placement(list(g), part))
-    return out
+    bit-compatible.  Spanned as ``repro.policy.fit``; the counter
+    ``repro.policy.shrunk`` takes the slices shrunk by one call."""
+    with spans.span("repro.policy.fit"):
+        out: list[Placement] = []
+        shrunk = 0
+        for g, p in zip(sched.groups, sched.partitions):
+            new_slices = list(p.slices)
+            changed = False
+            for pos, (si, s, _beta) in enumerate(p.slots):
+                if len(s.shares) != 1:
+                    continue
+                req = g[pos].requested_units
+                if req < s.units:
+                    new_slices[si] = Slice(req, s.shares)
+                    changed = True
+                    shrunk += 1
+            part = (Partition(tuple(new_slices),
+                              slice_label(tuple(new_slices)))
+                    if changed else p)
+            out.append(Placement(list(g), part))
+        spans.count("repro.policy.shrunk", shrunk)
+        return out
 
 
 class RLScheduler:
@@ -213,19 +219,22 @@ class RLScheduler:
         """The episode's host input, flat f32: ``(W, F)`` features (zero
         rows pad), ``(W,)`` validity, then the context block under
         ``obs_context`` (zero without a snapshot) — what
-        ``CoScheduleEnv`` would observe at reset."""
-        cfg = self.env_cfg
-        W, F = cfg.window, len(FEATURES)
-        assert len(queue) <= W, (len(queue), W)
-        out = np.zeros((W * F + W + context_dim(cfg),), np.float32)
-        out[:len(queue) * F] = np.asarray(
-            [j.features() for j in queue], np.float32).reshape(-1)
-        out[W * F:W * F + len(queue)] = 1.0
-        if cfg.obs_context and context is not None:
-            assert len(context.ages_s) == len(queue), \
-                (len(context.ages_s), len(queue))
-            out[W * F + W:] = context_block(context, W)
-        return out
+        ``CoScheduleEnv`` would observe at reset.  Spanned as
+        ``repro.sched.pack``, the context block as ``repro.sched.context``."""
+        with spans.span("repro.sched.pack"):
+            cfg = self.env_cfg
+            W, F = cfg.window, len(FEATURES)
+            assert len(queue) <= W, (len(queue), W)
+            out = np.zeros((W * F + W + context_dim(cfg),), np.float32)
+            out[:len(queue) * F] = np.asarray(
+                [j.features() for j in queue], np.float32).reshape(-1)
+            out[W * F:W * F + len(queue)] = 1.0
+            if cfg.obs_context and context is not None:
+                assert len(context.ages_s) == len(queue), \
+                    (len(context.ages_s), len(queue))
+                with spans.span("repro.sched.context"):
+                    out[W * F + W:] = context_block(context, W)
+            return out
 
     def schedule_submissions(self, submissions: list[tuple[str, JobProfile | None]],
                              context: DispatchContext | None = None) -> Schedule:

@@ -965,19 +965,24 @@ class ClusterSimulator:
         """EASY backfill: later dispatched groups may start now iff they fit
         the idle units and predictably finish by the blocked head's reserved
         start.  Backfilled claims give their units back before the head's
-        reservation, so the head can never be delayed."""
-        t_res = self._earliest_fit(pod, pod.ready[0].partition)
-        placed = False
-        for run in list(pod.ready)[1:]:
-            starts = find_offsets(run.partition, pod.free)
-            if starts is None:
-                continue
-            if now + run.pred.makespan <= t_res + 1e-9:
-                pod.ready.remove(run)
-                self._place(now, pod, run, starts, res, push, backfilled=True)
-                res.backfills += 1
-                placed = True
-        return placed
+        reservation, so the head can never be delayed.  Spanned as
+        ``repro.sim.backfill``; the counter ``repro.sim.backfilled`` takes
+        the groups one scan starts."""
+        with spans.span("repro.sim.backfill"):
+            t_res = self._earliest_fit(pod, pod.ready[0].partition)
+            started = 0
+            for run in list(pod.ready)[1:]:
+                starts = find_offsets(run.partition, pod.free)
+                if starts is None:
+                    continue
+                if now + run.pred.makespan <= t_res + 1e-9:
+                    pod.ready.remove(run)
+                    self._place(now, pod, run, starts, res, push,
+                                backfilled=True)
+                    res.backfills += 1
+                    started += 1
+            spans.count("repro.sim.backfilled", started)
+            return started > 0
 
     def _earliest_fit(self, pod: _Pod, partition) -> float:
         """Earliest time `partition` fits the pod, replaying outstanding
