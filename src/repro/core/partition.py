@@ -114,9 +114,8 @@ def _p(label, *slices) -> Partition:
     return Partition(tuple(slices), label)
 
 
-def enumerate_partitions(c_max: int = 4) -> list[Partition]:
-    """The curated partition table (Table VII analogue). Stable order —
-    the DQN's action indices point into this list."""
+def _build_table() -> tuple[Partition, ...]:
+    """The curated partition table (Table VII analogue), every arity."""
     table: list[Partition] = [
         _p("[{1.0},1m]", Slice(8, (1.0,))),                       # C=1 solo
     ]
@@ -151,7 +150,25 @@ def enumerate_partitions(c_max: int = 4) -> list[Partition]:
         _p("[{.25},.25m]x4",
            Slice(2, (1.0,)), Slice(2, (1.0,)), Slice(2, (1.0,)), Slice(2, (1.0,))),
     ]
-    return [p for p in table if p.arity <= c_max]
+    return tuple(table)
+
+
+def _solo(units: int) -> Partition:
+    s = Slice(units, (1.0,))
+    return Partition((s,), slice_label((s,)))
+
+
+_TABLE = _build_table()
+# single-slot partitions by width; the full pod's is the table's entry 0
+_SOLO = {w: _TABLE[0] if w == N_UNITS else _solo(w) for w in VALID_WIDTHS}
+
+
+def enumerate_partitions(c_max: int = 4) -> list[Partition]:
+    """The curated partition table (Table VII analogue) up to arity
+    ``c_max``. Stable order — the DQN's action indices point into this
+    list.  The table is built once; each call returns a new list over its
+    (frozen) partitions."""
+    return [p for p in _TABLE if p.arity <= c_max]
 
 
 def solo_partition(units: int = N_UNITS) -> Partition:
@@ -161,11 +178,11 @@ def solo_partition(units: int = N_UNITS) -> Partition:
     first-sight jobs run on in the online protocol; narrower widths are the
     placement layer's *right-sized* solo slices (a job whose trace carries a
     ``meta["units"]`` hint occupies only the slice it can actually use,
-    leaving the rest of the pod for concurrent groups)."""
-    if units == N_UNITS:
-        return enumerate_partitions(1)[0]
-    s = Slice(units, (1.0,))
-    return Partition((s,), slice_label((s,)))
+    leaving the rest of the pod for concurrent groups).  Built once per
+    width; the full pod's is ``enumerate_partitions(1)[0]``."""
+    if units not in _SOLO:
+        return _solo(units)          # raises: not a valid slice width
+    return _SOLO[units]
 
 
 def aligned_offsets(width: int) -> tuple[int, ...]:

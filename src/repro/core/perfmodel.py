@@ -51,31 +51,57 @@ class CoRunResult:
 
 def water_fill(demands: list[float], capacity: float = 1.0) -> list[float]:
     """Allocate bandwidth fractions: min(demand, fair share), redistributing
-    slack to the hungry (classic water-filling)."""
+    slack to the hungry (classic water-filling).
+
+    Each round sates, in index order, every job whose demand fits the fair
+    share of what is left; when none fits, the rest is split evenly.  A job
+    still in the running holds nothing yet, so its unmet demand is its
+    whole demand.  Two demands, the common case on a shared slice, take
+    the same rounds written out."""
     n = len(demands)
-    if n == 0:
-        return []
+    if n == 2 and capacity > 1e-12:
+        d0, d1 = demands
+        limit = capacity / 2 + 1e-15
+        if d0 <= limit:
+            if d1 <= limit:
+                return [d0, d1]
+            rest = capacity - d0
+            return [d0, (d1 if d1 <= rest + 1e-15 else rest) if rest > 1e-12 else 0.0]
+        if d1 <= limit:
+            rest = capacity - d1
+            return [(d0 if d0 <= rest + 1e-15 else rest) if rest > 1e-12 else 0.0, d1]
+        return [capacity / 2, capacity / 2]
     alloc = [0.0] * n
     remaining = capacity
-    active = list(range(n))
+    active = range(n)
     while active and remaining > 1e-12:
         fair = remaining / len(active)
-        sated = [i for i in active if demands[i] - alloc[i] <= fair + 1e-15]
-        if sated:
-            for i in sated:
-                remaining -= demands[i] - alloc[i]
-                alloc[i] = demands[i]
-            active = [i for i in active if i not in sated]
-        else:
-            for i in active:
-                alloc[i] += fair
-            remaining = 0.0
+        limit = fair + 1e-15
+        hungry = []
+        for i in active:
+            d = demands[i]
+            if d <= limit:
+                remaining -= d
+                alloc[i] = d
+            else:
+                hungry.append(i)
+        if len(hungry) == len(active):
+            for i in hungry:
+                alloc[i] = fair
+            break
+        active = hungry
     return alloc
 
 
-def _slice_step_times(jobs: list[JobProfile], betas: list[float], s: Slice,
-                      active: list[bool]) -> list[float]:
-    """Current per-step time for each active job on slice `s`.
+def _slice_step_times(c: list[float], m: list[float], xb: list[float],
+                      xl: list[float], fx: list[float],
+                      shared_mem: bool) -> list[float]:
+    """Current per-step time of each job running on one slice.
+
+    The arguments are the running jobs' slice terms: compute over its
+    share ``c``, memory ``m``, collective bytes ``xb`` and latency ``xl``,
+    and the fixed per-step time ``fx``.  ``shared_mem``: the jobs share the
+    slice's HBM (more than one runs on a multi-share slice).
 
     HBM bandwidth and ICI link bandwidth are physically shared: each job's
     bandwidth *utilization* (busy-time fraction) is water-filled against unit
@@ -84,96 +110,109 @@ def _slice_step_times(jobs: list[JobProfile], betas: list[float], s: Slice,
     chain (tiny payloads) and the κ stream-mixing loss contend without
     consuming bandwidth. Compute is divided by the static β shares.
     """
-    n_active = sum(active)
-    idx = [j for j in range(len(jobs)) if active[j]]
-    base = []
-    for j in idx:
-        c, m, x = jobs[j].terms(s.units, s.torus_factor)
-        base.append({
-            "c": c / betas[j], "m": m, "xb": x,
-            "xl": jobs[j].coll_latency(s.units),
-            "fixed": jobs[j].fixed_latency(s.units) + jobs[j].serial_s,
-        })
-    shared_mem = s.shared_memory and n_active > 1
-    multi = n_active > 1
-    mem_t = [b["m"] for b in base]        # memory time under current bw grant
-    coll_t = [b["xb"] for b in base]      # collective-bytes time, ditto
-    mem_u = [0.0] * len(base)
-    coll_u = [0.0] * len(base)
-
+    n = len(c)
+    if n == 1:       # alone: full bandwidth, no interference, no switching
+        return [max(c[0], m[0], xb[0] + xl[0]) + fx[0]]
+    jobs = range(n)
+    mem_t = list(m)                       # memory time under current bw grant
+    coll_t = list(xb)                     # collective-bytes time, ditto
     for _ in range(30):
-        st = [max(b["c"], mt, ct + b["xl"]) + b["fixed"]
-              for b, mt, ct in zip(base, mem_t, coll_t)]
-        mem_u = [min(1.0, b["m"] / t) for b, t in zip(base, st)]
-        coll_u = [min(1.0, b["xb"] / t) for b, t in zip(base, st)]
+        mem_u = [0.0] * n                 # utilizations, each min(1, term / step)
+        coll_u = [0.0] * n
+        for i in jobs:
+            t = max(c[i], mem_t[i], coll_t[i] + xl[i]) + fx[i]
+            u = m[i] / t
+            mem_u[i] = u if u < 1.0 else 1.0
+            u = xb[i] / t
+            coll_u[i] = u if u < 1.0 else 1.0
         ma = water_fill(mem_u) if shared_mem else mem_u
-        ca = water_fill(coll_u) if multi else coll_u
+        ca = water_fill(coll_u)
         delta = 0.0
-        for i, (b, u_m, a_m, u_x, a_x) in enumerate(zip(base, mem_u, ma, coll_u, ca)):
-            tgt_m = b["m"] / a_m if (shared_mem and a_m > 1e-12 and u_m > a_m + 1e-12) else b["m"]
-            tgt_x = b["xb"] / a_x if (multi and a_x > 1e-12 and u_x > a_x + 1e-12) else b["xb"]
+        for i in jobs:
+            a_m, a_x = ma[i], ca[i]
+            tgt_m = (m[i] / a_m if (shared_mem and a_m > 1e-12
+                                    and mem_u[i] > a_m + 1e-12) else m[i])
+            tgt_x = (xb[i] / a_x if (a_x > 1e-12 and coll_u[i] > a_x + 1e-12)
+                     else xb[i])
             delta += abs(tgt_m - mem_t[i]) + abs(tgt_x - coll_t[i])
             mem_t[i] += 0.5 * (tgt_m - mem_t[i])      # damped toward equilibrium
             coll_t[i] += 0.5 * (tgt_x - coll_t[i])
         if delta < 1e-9:
             break
 
-    out = [float("inf")] * len(jobs)
-    for i, (b, mt, ct, j) in enumerate(zip(base, mem_t, coll_t, idx)):
-        km = 1.0 + KAPPA_INTERFERENCE * (sum(mem_u) - mem_u[i]) if shared_mem else 1.0
-        kx = 1.0 + KAPPA_INTERFERENCE * (sum(coll_u) - coll_u[i]) if multi else 1.0
-        t = max(b["c"], mt * km, (ct + b["xl"]) * kx) + b["fixed"]
-        if n_active > 1:
-            t *= 1.0 + SIGMA_QUANTUM * (n_active - 1)
-        out[j] = t
+    mem_sum, coll_sum = sum(mem_u), sum(coll_u)
+    switch = 1.0 + SIGMA_QUANTUM * (n - 1)
+    out = []
+    for i in jobs:
+        km = 1.0 + KAPPA_INTERFERENCE * (mem_sum - mem_u[i]) if shared_mem else 1.0
+        kx = 1.0 + KAPPA_INTERFERENCE * (coll_sum - coll_u[i])
+        t = max(c[i], mem_t[i] * km, (coll_t[i] + xl[i]) * kx) + fx[i]
+        out.append(t * switch)
     return out
 
 
 def _simulate_slice(jobs: list[JobProfile], betas: list[float], s: Slice) -> list[float]:
-    """Phase simulation of one slice; returns per-job finish times."""
+    """Phase simulation of one slice; returns per-job finish times.
+
+    Each job's slice terms are computed once; every phase re-solves the
+    step times of the jobs still running."""
     n = len(jobs)
+    units, tf = s.units, s.torus_factor
+    c, m, xb, xl, fx = [], [], [], [], []
+    for job, beta in zip(jobs, betas):
+        cj, mj, xj = job.terms(units, tf)
+        c.append(cj / beta)
+        m.append(mj)
+        xb.append(xj)
+        xl.append(job.coll_latency(units))
+        fx.append(job.fixed_latency(units) + job.serial_s)
+    shared = s.shared_memory
     remaining = [float(j.steps) for j in jobs]
-    active = [True] * n
     finish = [0.0] * n
+    live = list(range(n))
     t = 0.0
     for _ in range(n):  # at most n phases
-        if not any(active):
+        if not live:
             break
-        st = _slice_step_times(jobs, betas, s, active)
+        st = _slice_step_times([c[j] for j in live], [m[j] for j in live],
+                               [xb[j] for j in live], [xl[j] for j in live],
+                               [fx[j] for j in live],
+                               shared and len(live) > 1)
         # time to next completion
-        dt = min(remaining[j] * st[j] for j in range(n) if active[j])
-        for j in range(n):
-            if active[j]:
-                remaining[j] -= dt / st[j]
-                if remaining[j] <= 1e-9:
-                    active[j] = False
-                    finish[j] = t + dt
+        dt = min(remaining[j] * st_j for j, st_j in zip(live, st))
+        still = []
+        for j, st_j in zip(live, st):
+            remaining[j] -= dt / st_j
+            if remaining[j] <= 1e-9:
+                finish[j] = t + dt
+            else:
+                still.append(j)
+        live = still
         t += dt
+    return finish
+
+
+def _finish_times(group: list[JobProfile], partition: Partition) -> list[float]:
+    """Per-job finish times of ``group`` under ``partition`` (jobs -> slots
+    in order); a slice's slots are consecutive, so each slice simulates
+    its own run of positions."""
+    assert len(group) == partition.arity, (len(group), partition.label)
+    finish: list[float] = []
+    for s in partition.slices:
+        pos = len(finish)
+        finish += _simulate_slice(group[pos:pos + len(s.shares)], s.shares, s)
     return finish
 
 
 def corun(group: list[JobProfile], partition: Partition) -> CoRunResult:
     """CoRunTime for `group` under `partition` (jobs -> slots in order)."""
-    slots = partition.slots
-    assert len(group) == len(slots), (len(group), partition.label)
-    # bucket group positions by slice (positional, so a job object appearing
-    # twice in a group keeps both finish times)
-    by_slice: dict[int, tuple[list[int], list[float], Slice]] = {}
-    for pos, (si, s, beta) in enumerate(slots):
-        bucket = by_slice.setdefault(si, ([], [], s))
-        bucket[0].append(pos)
-        bucket[1].append(beta)
-    finish = [0.0] * len(group)
-    for si, (positions, betas, s) in by_slice.items():
-        fts = _simulate_slice([group[p] for p in positions], betas, s)
-        for pos, ft in zip(positions, fts):
-            finish[pos] = ft
+    finish = _finish_times(group, partition)
     solo = [j.solo_time() for j in group]
     return CoRunResult(makespan=max(finish), finish_times=finish, solo_times=solo)
 
 
 def corun_time(group: list[JobProfile], partition: Partition) -> float:
-    return corun(group, partition).makespan
+    return max(_finish_times(group, partition))
 
 
 def solo_run_time(group: list[JobProfile]) -> float:

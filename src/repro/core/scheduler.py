@@ -260,14 +260,25 @@ class RLScheduler:
                                    on_window=on_window, context=context)
 
     def _enforce_constraints(self, sched: Schedule) -> Schedule:
+        """The §IV-A guard: a multi-job group whose predicted co-run loses
+        to time sharing is split into solo runs.  Spanned as
+        ``repro.sched.guard``; the counters ``repro.sched.guard_groups`` and
+        ``repro.sched.fallback`` take the multi-job groups one call
+        evaluates and the groups it splits."""
         with spans.span("repro.sched.guard"):
             solo = solo_partition()
             out = Schedule()
+            evaluated = split = 0
             for g, p in zip(sched.groups, sched.partitions):
-                if len(g) > 1 and corun_time(g, p) > solo_run_time(g):
-                    self.stats.fallback_groups += 1
-                    for j in g:
-                        out.add([j], solo)
-                else:
-                    out.add(g, p)
+                if len(g) > 1:
+                    evaluated += 1
+                    if corun_time(g, p) > solo_run_time(g):
+                        split += 1
+                        for j in g:
+                            out.add([j], solo)
+                        continue
+                out.add(g, p)
+            self.stats.fallback_groups += split
+            spans.count("repro.sched.guard_groups", evaluated)
+            spans.count("repro.sched.fallback", split)
             return out

@@ -7,6 +7,7 @@ name; and the recorder observes without steering, so a simulation makes
 the same decisions with it on or off.
 """
 import dataclasses
+import random
 from pathlib import Path
 
 import jax
@@ -15,6 +16,7 @@ import pytest
 from repro import spans
 from repro.core import DQNAgent, DQNConfig, EnvConfig, RLScheduler, make_zoo
 from repro.core.env import CoScheduleEnv
+from repro.core.perfmodel import corun_time, solo_run_time
 from repro.online import ClusterSimulator, RLDispatchPolicy, poisson_trace
 
 ZOO = make_zoo()
@@ -156,6 +158,40 @@ def test_spans_observe_and_never_steer():
     assert all(s.name.startswith("repro.") for s in spans.records())
     (run,) = [s for s in spans.records() if s.name == "repro.sim.run"]
     assert {s.root for s in spans.records()} == {run.id}
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_guard_counts_its_groups_and_the_splits(seed, monkeypatch):
+    """One ``schedule`` call: ``repro.sched.guard_groups`` takes the plan's
+    multi-job groups and ``repro.sched.fallback`` the groups split, which
+    is what ``stats.fallback_groups`` adds."""
+    sched = RLScheduler(_agent(seed), ENV_CFG)
+    plans = []
+    guard = sched._enforce_constraints
+
+    def spy(plan):
+        plans.append(plan)
+        return guard(plan)
+
+    monkeypatch.setattr(sched, "_enforce_constraints", spy)
+    rng = random.Random(0)
+    spans.enable()
+    groups = splits = 0
+    for _ in range(6):
+        spans.reset()
+        before = sched.stats.fallback_groups
+        out = sched.schedule(rng.sample(ZOO, 4))
+        multi = [(g, p) for g, p in zip(plans[-1].groups, plans[-1].partitions)
+                 if len(g) > 1]
+        split = sum(corun_time(g, p) > solo_run_time(g) for g, p in multi)
+        counters = spans.counters()
+        assert counters["repro.sched.guard_groups"] == [len(multi)]
+        assert counters["repro.sched.fallback"] == [split]
+        assert sched.stats.fallback_groups - before == split
+        assert len(out.groups) == len(plans[-1].groups) + sum(
+            len(g) - 1 for g, p in multi if corun_time(g, p) > solo_run_time(g))
+        groups, splits = groups + len(multi), splits + split
+    assert groups >= splits > 0
 
 
 def test_act_spans_nest_put_launch_fetch():
