@@ -12,7 +12,7 @@
 
 Groups run concurrently on disjoint slice ranges (EASY backfill included);
 pick ``--trace fragmented`` to see right-sized 1-unit mice pack around
-full-pod jobs, or ``--blocking`` for the whole-pod PR-3 dispatch mode.
+full-pod jobs.
 ``--context`` trains the agent on the arrival-aware observation (profiles
 + busy-unit mask + queue ages + pending depth — docs/observation.md) and
 the simulator then feeds it the real cluster snapshot at every dispatch
@@ -51,8 +51,6 @@ def main():
     ap.add_argument("--trace", choices=sorted(TRACE_FAMILIES), default="poisson")
     ap.add_argument("--load", type=float, default=1.25)
     ap.add_argument("--retrain-interval-min", type=float, default=30.0)
-    ap.add_argument("--blocking", action="store_true",
-                    help="PR-3 whole-pod block dispatch (no concurrency)")
     ap.add_argument("--context", action="store_true",
                     help="arrival-aware observation: train with sampled "
                          "cluster-state contexts and serve with the "
@@ -69,7 +67,6 @@ def main():
                          "chrome://tracing): one track per pod x slice "
                          "unit")
     args = ap.parse_args()
-    mode = "blocking" if args.blocking else "concurrent"
     pods = tuple(int(w) for w in args.pods.split(","))
 
     zoo = make_zoo()
@@ -94,7 +91,7 @@ def main():
           f"fleet {pods} via '{args.router}' router)")
 
     def cfg(tick=None):
-        return SimConfig(window=args.window, mode=mode, pods=pods,
+        return SimConfig(window=args.window, pods=pods,
                          router=args.router, tick_interval_s=tick)
 
     results = {}

@@ -128,15 +128,12 @@ class DispatchDecision:
     """The single result of one dispatch window — what
     ``DispatchPolicy.decide`` returns.
 
-    Collapses the historical ``dispatch()`` (schedule), ``placements()``
-    (width-fitted placements) and per-call stats bookkeeping into one
-    value: ``schedule`` is the planned :class:`Schedule` (``None`` only
-    when a legacy ``placements``-override subclass produced the
-    placements without one), ``placements`` is what the slice-level
+    ``schedule`` is the planned :class:`Schedule`, ``placements`` its
+    width-fitted form (:func:`to_placements`) that the slice-level
     simulator consumes, and ``first_sight`` / ``planned`` count this
     window's submissions on each side of the profiling protocol."""
 
-    schedule: Schedule | None
+    schedule: Schedule
     placements: tuple[Placement, ...]
     first_sight: int = 0
     planned: int = 0

@@ -67,8 +67,7 @@ owns nothing but the Python-side history bookkeeping, and identical
 carries replay identically (the determinism test pins this).
 
 ``train_agent_scalar`` preserves the seed per-step Python loop verbatim —
-it is the semantic reference for the parity test and the baseline for
-``benchmarks/train_throughput.py``.
+it is the semantic reference for the parity test.
 
 Random queues over the zoo, ε-greedy exploration, dueling double-DQN
 updates; held-out jobs excluded (paper's unseen-application generalization
@@ -81,9 +80,8 @@ the classic DQN ratio).  The vectorized default is 1 update per
 DeepMind-classic cadence; with the target network synced on a fixed
 *transition* cadence this trains schedulers whose throughput clears the
 seed acceptance bar across seeds.  Set ``update_every=1`` to recover the
-seed's update work exactly (at matched update work the scanned engine is
-no faster than the scalar loop — updates dominate; see BENCH_train.json's
-``speedup_matched_updates``).
+seed's update work exactly (at that cadence the DQN updates, not the
+rollout, dominate the work).
 """
 from __future__ import annotations
 

@@ -2,8 +2,8 @@
 
     PYTHONPATH=src python -m repro.launch.schedule --episodes 2000 --window 12
 
-Trains (or loads) the DQN agent over the job zoo, schedules the Q1..Q12
-queues, and prints the five-method comparison (paper Fig. 8).
+Trains the DQN agent over the job zoo, schedules the Q1..Q12 queues, and
+prints the five-method comparison (paper Fig. 8).
 """
 from __future__ import annotations
 
@@ -20,13 +20,18 @@ def main():
     ap.add_argument("--per-kind", type=int, default=3)
     args = ap.parse_args()
 
-    from benchmarks.common import trained_agent
     from repro.core import (
-        POLICIES, RLScheduler, make_zoo, paper_queues, summarize, validate_schedule,
+        POLICIES, DQNConfig, EnvConfig, RLScheduler, TrainConfig, make_zoo,
+        paper_queues, summarize, train_agent, validate_schedule,
     )
 
     zoo = make_zoo()
-    agent, env_cfg = trained_agent(zoo, args.window, args.c_max, episodes=args.episodes)
+    env_cfg = EnvConfig(window=args.window, c_max=args.c_max)
+    agent, _ = train_agent(
+        zoo, env_cfg,
+        TrainConfig(episodes=args.episodes,
+                    eval_every=max(100, args.episodes // 4),
+                    dqn=DQNConfig(eps_decay_steps=max(1500, args.episodes * 7))))
     sched = RLScheduler(agent, env_cfg)
     queues = paper_queues(zoo, window=args.window, per_kind=args.per_kind)
 

@@ -22,9 +22,8 @@ delaying it.  Each group's FREE event is keyed by its claimed slice
 ranges.  Per-job wait/turnaround, cluster makespan/throughput/utilization,
 and slice-level fragmentation metrics (idle-slice fraction, per-slice
 utilization timeline) land in a
-:class:`~repro.online.simulator.SimResult`; ``mode="blocking"`` recovers
-the PR-3 whole-pod block dispatch bit-compatibly.  Everything is
-deterministic given the trace seed.
+:class:`~repro.online.simulator.SimResult`.  Everything is deterministic
+given the trace seed.
 
 Fleet serving
 -------------

@@ -6,7 +6,9 @@ seen runs **solo** on the full pod (being profiled as it runs) and its
 profile enters the repository, while previously-profiled jobs are
 co-scheduled by the policy's planner.  All policies therefore pay the same
 first-sight profiling cost — comparisons across policies on one trace are
-apples to apples.
+apples to apples.  :meth:`DispatchPolicy.decide` is every policy's one
+entry point: it returns the window's schedule and its width-fitted
+placements together.
 
 Every dispatch additionally receives the simulator's
 :class:`~repro.core.env.DispatchContext` — the free-unit occupancy mask,
@@ -34,7 +36,6 @@ alone, exactly as before.
 """
 from __future__ import annotations
 
-import warnings
 from dataclasses import dataclass
 
 from repro import spans
@@ -45,8 +46,7 @@ from repro.core.perfmodel import solo_run_time
 from repro.core.problem import Schedule
 from repro.core.profiles import JobProfile, ProfileRepository
 from repro.core.scheduler import (
-    DispatchDecision, Placement, RLScheduler, submission_protocol,
-    to_placements,
+    DispatchDecision, RLScheduler, submission_protocol, to_placements,
 )
 
 
@@ -66,11 +66,9 @@ class DispatchPolicy:
     does — and returns one
     :class:`~repro.core.scheduler.DispatchDecision` carrying the planned
     schedule, the width-fitted placements the slice-level simulator
-    consumes, and this window's first-sight/planned counts.  The
-    historical ``dispatch()`` / ``placements()`` methods survive as thin
-    deprecation shims over the same protocol; subclasses that still
-    override them (the pre-decide extension points) are honored —
-    :meth:`decide` detects the override and routes through it.
+    consumes, and this window's first-sight/planned counts.  Subclasses
+    extend it through :meth:`plan` (or, like the RL policy, by replacing
+    the protocol body ``_plan_schedule``).
 
     ``plan_window`` caps how many profiled jobs reach one :meth:`plan` call
     (chunked like the RL window); ``None`` plans the whole batch at once.
@@ -85,7 +83,6 @@ class DispatchPolicy:
         self.repository = repository if repository is not None else ProfileRepository()
         self.plan_window = plan_window
         self.stats = PolicyStats()
-        self._last_schedule: Schedule | None = None
 
     # ------------------------------------------------ the one entry point
 
@@ -98,21 +95,9 @@ class DispatchPolicy:
         is *not* forwarded — the RL delegate consumes it."""
         with spans.span("repro.policy.decide"):
             before = (self.stats.unprofiled_jobs, self.stats.planned_jobs)
-            cls = type(self)
-            if cls.dispatch is not DispatchPolicy.dispatch:
-                # legacy subclass extension point: honor the override (its
-                # super() chain lands back in the shim below)
-                sched = self.dispatch(submissions, context=context)
-                pls = to_placements(sched)
-            elif cls.placements is not DispatchPolicy.placements:
-                self._last_schedule = None
-                pls = self.placements(submissions, context=context)
-                sched = self._last_schedule
-            else:
-                sched = self._plan_schedule(submissions, context=context)
-                pls = to_placements(sched)
+            sched = self._plan_schedule(submissions, context=context)
             return DispatchDecision(
-                schedule=sched, placements=tuple(pls),
+                schedule=sched, placements=tuple(to_placements(sched)),
                 first_sight=self.stats.unprofiled_jobs - before[0],
                 planned=self.stats.planned_jobs - before[1])
 
@@ -128,28 +113,6 @@ class DispatchPolicy:
                                    window=self.plan_window,
                                    on_unprofiled=on_unprofiled,
                                    on_window=on_window)
-
-    # ------------------------------------------------- deprecation shims
-
-    def dispatch(self, submissions: list[tuple[str, JobProfile | None]],
-                 context=None) -> Schedule:
-        """Deprecated: ``decide(...).schedule`` replaces this."""
-        warnings.warn(
-            "DispatchPolicy.dispatch() is deprecated; use "
-            "decide(submissions, context).schedule",
-            DeprecationWarning, stacklevel=2)
-        sched = self._plan_schedule(submissions, context=context)
-        self._last_schedule = sched
-        return sched
-
-    def placements(self, submissions: list[tuple[str, JobProfile | None]],
-                   context=None) -> list[Placement]:
-        """Deprecated: ``decide(...).placements`` replaces this."""
-        warnings.warn(
-            "DispatchPolicy.placements() is deprecated; use "
-            "decide(submissions, context).placements",
-            DeprecationWarning, stacklevel=2)
-        return to_placements(self.dispatch(submissions, context=context))
 
     def plan(self, queue: list[JobProfile]) -> Schedule:
         raise NotImplementedError

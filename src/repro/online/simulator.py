@@ -33,8 +33,8 @@ policy planned wider than the pod (e.g. an 8-unit MPS pair on a 4-unit
 pod) is decomposed back into right-sized solo placements — counted in
 ``SimResult.refits``.
 
-Slice-level occupancy (``mode="concurrent"``, the default)
-----------------------------------------------------------
+Slice-level occupancy
+---------------------
 Each pod is an occupancy map over its slice units, not a scalar busy
 flag.  Whenever slice units are idle and the pod's dispatched-group queue
 is empty, the FCFS head of its pending queue (up to ``window``
@@ -55,14 +55,10 @@ expiries — no new work is admitted past a blocked head, so the reservation
 is exact) and a **backfill** scan lets later groups of the already-
 dispatched queue start immediately *iff* they fit the idle units now and
 their predicted makespan ends by the head's reserved start — EASY-style
-backfill, so jumping the queue can never delay the head.
-
-``mode="blocking"`` recovers the PR-3 whole-pod semantics bit-compatibly
-(it requires a fleet of full-width pods): one window's groups execute
-back to back on the full pod and the pod is released only when the whole
-block drains.  On traces without sub-pod width hints the two modes
-produce identical results (all placements are full-pod, so concurrency
-never materializes) — the regression tests pin this equivalence.
+backfill, so jumping the queue can never delay the head.  On traces
+without sub-pod width hints every placement claims the whole pod, so a
+window's groups run back to back and no unit is ever idle behind a
+blocked head.
 
 Dispatch-time context
 ---------------------
@@ -105,7 +101,7 @@ from repro.core.env import DispatchContext
 from repro.core.partition import N_UNITS, VALID_WIDTHS, find_offsets, solo_partition
 from repro.core.perfmodel import CoRunResult, corun
 from repro.core.profiles import JobProfile
-from repro.core.scheduler import DispatchDecision, Placement, to_placements
+from repro.core.scheduler import Placement
 from repro.online.router import FleetView, PodView, Router, make_router
 
 _ARRIVE, _TICK, _FREE = 0, 1, 2          # same-time resolution order
@@ -120,11 +116,9 @@ class SimConfig:
     power-of-two; the widest must be ``N_UNITS`` so unhinted full-pod
     submissions always have an eligible pod).  ``router``/``router_seed``
     select the arrival router (:mod:`repro.online.router`) — irrelevant,
-    but still recorded, for single-pod fleets.  ``mode="blocking"``
-    (the PR-3 whole-pod dispatch) requires a uniform full-width fleet."""
+    but still recorded, for single-pod fleets."""
 
     window: int = 8
-    mode: str = "concurrent"
     backfill: bool = True
     tick_interval_s: float | None = None
     pods: tuple[int, ...] = (N_UNITS,)
@@ -134,15 +128,11 @@ class SimConfig:
     def __post_init__(self):
         object.__setattr__(self, "pods", tuple(self.pods))
         assert self.window >= 1
-        assert self.mode in ("concurrent", "blocking"), self.mode
         assert self.pods, "fleet needs at least one pod"
         for w in self.pods:
             assert w in VALID_WIDTHS, f"invalid pod width {w}"
         assert max(self.pods) == N_UNITS, \
             "widest pod must be full-width (unhinted jobs request N_UNITS)"
-        if self.mode == "blocking":
-            assert all(w == N_UNITS for w in self.pods), \
-                "blocking mode models whole-pod dispatch: widths must be N_UNITS"
 
     @property
     def n_pods(self) -> int:
@@ -241,7 +231,6 @@ class SimResult:
     policy: str
     window: int
     jobs: list[JobRecord]
-    mode: str = "concurrent"
     timeline: list[Segment] = field(default_factory=list)
     busy_time: float = 0.0
     dispatches: int = 0
@@ -361,13 +350,12 @@ class SimResult:
                 if self.jobs else 0.0)
 
     def summary(self) -> dict:
-        """JSON-able digest for BENCH_online.json (``schema: 2``: the
-        fleet-era layout — adds ``n_pods``/``pods``/``router``/``refits``
-        and redefines utilization as the per-pod mean)."""
+        """JSON-able digest of the run (``schema: 2``: the fleet-era
+        layout — adds ``n_pods``/``pods``/``router``/``refits`` and
+        redefines utilization as the per-pod mean)."""
         return {
             "schema": 2,
             "policy": self.policy,
-            "mode": self.mode,
             "n_pods": self.n_pods,
             "pods": list(self.pods),
             "router": self.router,
@@ -540,8 +528,8 @@ class _Pod:
     is a full-width occupancy map whose upper units start — and stay —
     busy, so the shared placement arithmetic needs no width parameter."""
 
-    __slots__ = ("idx", "width", "offset", "pending", "ready", "busy",
-                 "free", "claims", "cid", "n_busy_units", "busy_t0")
+    __slots__ = ("idx", "width", "offset", "pending", "ready", "free",
+                 "claims", "cid", "n_busy_units", "busy_t0")
 
     def __init__(self, idx: int, width: int, offset: int):
         self.idx = idx
@@ -549,7 +537,6 @@ class _Pod:
         self.offset = offset             # first index on the fleet unit axis
         self.pending: deque = deque()
         self.ready: deque[_Run] = deque()
-        self.busy = False                # blocking-mode pod flag
         self.free = [u < width for u in range(N_UNITS)]
         self.claims: dict[int, tuple[tuple[tuple[int, int], ...], float]] = {}
         self.cid = 0
@@ -563,14 +550,12 @@ class ClusterSimulator:
 
     Configuration lives in a frozen :class:`SimConfig` (pass ``config=``;
     the historical keyword arguments remain as a legacy construction path
-    and simply populate one).  ``mode="concurrent"`` (default) places each
-    dispatched group onto disjoint slice-unit ranges so independent groups
-    run side by side; ``backfill=True`` additionally lets later groups of
-    a pod's dispatched queue jump a blocked head into idle units when
-    their predicted finish cannot delay the head's reserved start.
-    ``mode="blocking"`` is the PR-3 whole-pod block dispatch, kept
-    bit-compatible for regression.  Fleets longer than one pod route each
-    arrival through ``config.router`` at its arrival instant.
+    and simply populate one).  Each dispatched group is placed onto
+    disjoint slice-unit ranges so independent groups run side by side;
+    ``backfill=True`` additionally lets later groups of a pod's dispatched
+    queue jump a blocked head into idle units when their predicted finish
+    cannot delay the head's reserved start.  Fleets longer than one pod
+    route each arrival through ``config.router`` at its arrival instant.
 
     ``on_tick(now, sim)`` fires every ``tick_interval_s`` of simulated time
     while work remains — the MISO-style re-training loop hangs off it (see
@@ -587,13 +572,13 @@ class ClusterSimulator:
 
     def __init__(self, policy, config: SimConfig | None = None, *,
                  window: int = 8, tick_interval_s: float | None = None,
-                 on_tick=None, mode: str = "concurrent",
-                 backfill: bool = True, pods: tuple[int, ...] | None = None,
+                 on_tick=None, backfill: bool = True,
+                 pods: tuple[int, ...] | None = None,
                  router: str = "hash", router_seed: int = 0,
                  telemetry=None):
         if config is None:
             config = SimConfig(
-                window=window, mode=mode, backfill=backfill,
+                window=window, backfill=backfill,
                 tick_interval_s=tick_interval_s,
                 pods=tuple(pods) if pods is not None else (N_UNITS,),
                 router=router, router_seed=router_seed)
@@ -606,7 +591,6 @@ class ClusterSimulator:
         # legacy attribute mirrors (config is the source of truth)
         self.window = config.window
         self.tick_interval_s = config.tick_interval_s
-        self.mode = config.mode
         self.backfill = config.backfill
         self._router: Router = make_router(config.router, config.router_seed)
         self._pods: list[_Pod] = []
@@ -629,7 +613,7 @@ class ClusterSimulator:
         cfg = self.config
         span = spans.span
         res = SimResult(policy=getattr(self.policy, "name", "policy"),
-                        window=cfg.window, jobs=[], mode=cfg.mode,
+                        window=cfg.window, jobs=[],
                         slice_busy_s=[0.0] * cfg.total_units,
                         pods=cfg.pods, router=cfg.router)
         heap: list[tuple[float, int, int, object]] = []
@@ -663,8 +647,7 @@ class ClusterSimulator:
         n_pods = cfg.n_pods
 
         def work_left():
-            return any(p.pending or p.ready or p.busy or p.claims
-                       for p in self._pods)
+            return any(p.pending or p.ready or p.claims for p in self._pods)
 
         tel = self.telemetry
 
@@ -684,11 +667,7 @@ class ClusterSimulator:
                                   order[i].profile.requested_units)
             elif kind == _FREE:
                 pidx, cid = payload
-                pod = self._pods[pidx]
-                if cfg.mode == "blocking":
-                    pod.busy = False
-                else:
-                    self._release(now, pod, cid, res)
+                self._release(now, self._pods[pidx], cid, res)
                 if tel is not None:
                     tel.on_free(now, pidx, cid)
             else:  # _TICK — only while work remains (no retrain on a drained
@@ -707,7 +686,6 @@ class ClusterSimulator:
         qd_int = bu_int = 0.0
         pods = self._pods
         pod0 = pods[0] if len(pods) == 1 else None   # single-pod fast path
-        blocking = cfg.mode == "blocking"
         while heap:
             now, kind, _, payload = heapq.heappop(heap)
             if tel is not None and now > prev_t:
@@ -718,14 +696,12 @@ class ClusterSimulator:
                 dt = now - prev_t
                 if pod0 is not None:
                     qd = len(pod0.pending)
-                    bu = (pod0.width if pod0.busy else 0) if blocking \
-                        else pod0.n_busy_units
+                    bu = pod0.n_busy_units
                 else:
                     qd = bu = 0
                     for p in pods:
                         qd += len(p.pending)
-                        bu += (p.width if p.busy else 0) if blocking \
-                            else p.n_busy_units
+                        bu += p.n_busy_units
                 qd_int += qd * dt
                 bu_int += bu * dt
                 prev_t = now
@@ -737,11 +713,7 @@ class ClusterSimulator:
                 _, kind2, _, payload2 = heapq.heappop(heap)
                 handle(now, kind2, payload2)
             for pod in self._pods:
-                if cfg.mode == "blocking":
-                    self._dispatch_blocking(now, pod, res, order, records,
-                                            push)
-                else:
-                    self._service(now, pod, res, order, records, push)
+                self._service(now, pod, res, order, records, push)
         if tel is not None:
             tel.on_clock_totals(qd_int, bu_int, qd, bu)
         for pod in self._pods:
@@ -764,10 +736,7 @@ class ClusterSimulator:
     def live_idle_frac(self) -> float:
         """Instantaneous fraction of fleet units unclaimed — the drift
         monitor's occupancy signal at tick time."""
-        if self.config.mode == "blocking":
-            busy = sum(p.width if p.busy else 0 for p in self._pods)
-        else:
-            busy = sum(p.n_busy_units for p in self._pods)
+        busy = sum(p.n_busy_units for p in self._pods)
         return 1.0 - busy / self.config.total_units
 
     # --------------------------------------------------------- fleet view
@@ -778,95 +747,18 @@ class ClusterSimulator:
         instant — the router's whole world."""
         views = []
         for p in self._pods:
-            if self.config.mode == "blocking":
-                free = tuple([not p.busy] * p.width)
-                busy_units = p.width if p.busy else 0
-            else:
-                free = tuple(p.free[:p.width])
-                busy_units = p.n_busy_units
             queue_units = sum(r.partition.total_units for r in p.ready)
             queue_units += sum(
                 min(order[i].profile.requested_units, p.width)
                 for i in p.pending)
-            views.append(PodView(idx=p.idx, width=p.width, free=free,
+            views.append(PodView(idx=p.idx, width=p.width,
+                                 free=tuple(p.free[:p.width]),
                                  pending=len(p.pending), ready=len(p.ready),
                                  queue_units=queue_units,
-                                 busy_units=busy_units))
+                                 busy_units=p.n_busy_units))
         return FleetView(pods=tuple(views), now_s=now)
 
-    # ------------------------------------------------ policy entry point
-
-    def _decide(self, subs, ctx) -> DispatchDecision:
-        """One call site for the policy: the unified ``decide`` API, with
-        a duck-typing adapter for external policies that still only
-        implement the legacy ``placements``/``dispatch`` surface."""
-        pol = self.policy
-        if hasattr(pol, "decide"):
-            return pol.decide(subs, context=ctx)
-        if hasattr(pol, "placements"):
-            return DispatchDecision(
-                schedule=None,
-                placements=tuple(pol.placements(subs, context=ctx)))
-        sched = pol.dispatch(subs, context=ctx)
-        return DispatchDecision(schedule=sched,
-                                placements=tuple(to_placements(sched)))
-
-    # ------------------------------------------------- blocking (PR-3) mode
-
-    def _dispatch_blocking(self, now, pod: _Pod, res, order, records,
-                           push) -> None:
-        """Whole-pod block dispatch — the PR-3 event model, verbatim (the
-        dispatch context reports the idle full pod, which it is whenever a
-        blocking dispatch fires)."""
-        if pod.busy or not pod.pending:
-            return
-        head = [pod.pending.popleft()
-                for _ in range(min(self.window, len(pod.pending)))]
-        decision = self._decide(
-            [(order[i].binary, order[i].profile) for i in head],
-            self._dispatch_context(now, pod, head, order,
-                                   free=(True,) * N_UNITS))
-        sched = decision.schedule
-        assert sched is not None, \
-            "blocking mode needs a schedule-producing policy"
-        by_name: dict[str, deque] = defaultdict(deque)
-        for i in head:
-            by_name[order[i].profile.name].append(records[i])
-        tel = self.telemetry
-        if tel is not None:
-            tel.on_window(now, pod.idx, head, len(pod.pending))
-        t0 = now
-        for g, p in zip(sched.groups, sched.partitions):
-            block = corun(g, p)
-            grecs = []
-            for job, ft in zip(g, block.finish_times):
-                rec = by_name[job.name].popleft()
-                # dispatch = the group's actual start, not the block
-                # hand-off: jobs queued behind earlier groups of the same
-                # block are still *waiting*, and a policy that forms many
-                # sequential groups must not hide that queueing delay
-                rec.dispatch = t0
-                rec.finish = t0 + ft
-                rec.group_size = len(g)
-                rec.partition = p.label
-                grecs.append(rec)
-            res.timeline.append(Segment(t0, t0 + block.makespan, len(g),
-                                        p.label, slices=((0, N_UNITS),),
-                                        pod=pod.idx))
-            for u in range(N_UNITS):
-                res.slice_busy_s[pod.offset + u] += block.makespan
-            if tel is not None:
-                tel.on_place(t0, pod.idx, grecs, ((0, N_UNITS),),
-                             t0 + block.makespan, None, p.label, False)
-            t0 += block.makespan
-        leftover = [n for n, d in by_name.items() if d]
-        assert not leftover, f"policy dropped submissions: {leftover}"
-        res.busy_time += t0 - now
-        res.dispatches += 1
-        pod.busy = True
-        push(t0, _FREE, (pod.idx, None))
-
-    # --------------------------------------------- concurrent (slice) mode
+    # ------------------------------------------------------- slice dispatch
 
     def _service(self, now, pod: _Pod, res, order, records, push) -> None:
         """Place one pod's dispatched groups onto its free slice units.
@@ -876,8 +768,8 @@ class ClusterSimulator:
         windows).  With backfill enabled, a *blocked* head additionally
         admits one lookahead window while idle units exist, so small later
         arrivals become backfill candidates — on full-pod-only traces no
-        units are ever free while the head is blocked, which is what keeps
-        this mode bit-compatible with blocking dispatch there."""
+        units are ever free while the head is blocked, so windows there
+        run back to back on the whole pod."""
         while True:
             progress = False
             # FCFS: place the head while it fits
@@ -903,8 +795,7 @@ class ClusterSimulator:
             if not progress:
                 return
 
-    def _dispatch_context(self, now, pod: _Pod, head, order,
-                          free=None) -> DispatchContext:
+    def _dispatch_context(self, now, pod: _Pod, head, order) -> DispatchContext:
         """Pod-state snapshot handed to the policy with each window: the
         live free-unit mask (the same list ``find_offsets`` places
         against — a narrow pod's missing upper units read busy), each head
@@ -912,7 +803,7 @@ class ClusterSimulator:
         queue left behind — the arrival-aware observation an
         ``obs_context`` agent folds into its state."""
         return DispatchContext(
-            free_units=tuple(pod.free) if free is None else free,
+            free_units=tuple(pod.free),
             ages_s=tuple(now - order[i].t for i in head),
             queue_depth=len(pod.pending),
             now_s=now)
@@ -944,7 +835,7 @@ class ClusterSimulator:
                     for _ in range(min(self.window, len(pod.pending)))]
             subs = [(order[i].binary, order[i].profile) for i in head]
             ctx = self._dispatch_context(now, pod, head, order)
-            decision = self._decide(subs, ctx)
+            decision = self.policy.decide(subs, context=ctx)
             by_name: dict[str, deque] = defaultdict(deque)
             for i in head:
                 by_name[order[i].profile.name].append(records[i])

@@ -51,7 +51,7 @@ Wall-clock spans
 :mod:`repro.spans` (re-exported here as ``spans``) times the live
 dispatch path itself: simulator run, window, ``decide``, the RL episode,
 its environment steps and guard, and each agent round trip.  It is off by
-default; ``benchmarks/online_sim.py --profile`` switches it on.
+default; ``spans.enable()`` switches it on.
 """
 from __future__ import annotations
 
@@ -284,7 +284,7 @@ class TraceRecorder:
         spans: dict[int, dict] = {}
         claim_free: dict[tuple[int, int], float] = {}
         for e in self.events:
-            if e["kind"] == "free" and e.get("claim") is not None:
+            if e["kind"] == "free":
                 claim_free[(e["pod"], e["claim"])] = e["t_s"]
         for e in self.events:
             k = e["kind"]
@@ -303,9 +303,8 @@ class TraceRecorder:
                         spans[j]["place"] = e["t_s"]
                         spans[j]["run_end"] = e["t1_s"]
                         spans[j]["backfilled"] = e.get("backfilled", False)
-                        if e.get("claim") is not None:
-                            spans[j]["free"] = claim_free.get(
-                                (e["pod"], e["claim"]))
+                        spans[j]["free"] = claim_free.get(
+                            (e["pod"], e["claim"]))
         return spans
 
     # ----------------------------------------------------------- exports

@@ -1546,7 +1546,7 @@ class VectorizedClusterSimulator:
     ``run(trace)`` returns a :class:`~repro.online.simulator.SimResult`
     built from the device lanes (records in sorted-trace order, timeline
     in placement order — the same shapes the heap produces), so every
-    downstream consumer (summaries, percentiles, benchmarks) is shared.
+    downstream consumer (summaries, percentiles, parity checks) is shared.
     ``sweep(traces)`` evaluates a batch in one vmapped call (sharded over
     host devices via ``pmap`` when ``devices`` is given) and returns
     per-trace :class:`SweepSummary` lanes.
@@ -1558,8 +1558,8 @@ RLDispatchPolicy`, whose agent episodes then run in-graph at the
     never recompiles, and ``sweep(..., param_sets=[...])`` adds a
     leading params axis evaluating a population of agents in one call.
     Use :meth:`supports` to route other policies to the heap.  No
-    ``on_tick``/re-training (host callbacks cannot run in-graph) and no
-    ``mode="blocking"`` — the heap remains the only path for both.
+    ``on_tick``/re-training (host callbacks cannot run in-graph) — the
+    heap remains the only path for it.
     """
 
     def __init__(self, policy=None, window: int = 8, backfill: bool = True,
@@ -1628,7 +1628,7 @@ RLDispatchPolicy`, whose agent episodes then run in-graph at the
 
     def run(self, trace: list[Arrival]) -> SimResult:
         res = SimResult(policy=getattr(self.policy, "name", "time_sharing"),
-                        window=self.window, jobs=[], mode="concurrent")
+                        window=self.window, jobs=[])
         if not trace:
             return res
         jobs: list = []
@@ -1786,7 +1786,7 @@ class VectorizedFleetSimulator:
 
     State-dependent routers (``least_loaded``/``frag``) couple the pods
     through the live :class:`FleetView` and stay heap-only, as do
-    ``mode="blocking"``, ``on_tick`` re-training, and policies outside
+    ``on_tick`` re-training and policies outside
     time-sharing/RL (:meth:`supports` mirrors
     :class:`VectorizedClusterSimulator`).  With an
     :class:`~repro.online.policies.RLDispatchPolicy` every pod lane runs
@@ -1818,9 +1818,8 @@ class VectorizedFleetSimulator:
                 f"vectorized fleet requires the state-free 'hash' router "
                 f"(got {config.router!r}); state-dependent routers couple "
                 f"pods and run on the heap ClusterSimulator")
-        if config.mode != "concurrent" or config.tick_interval_s:
-            raise ValueError("vectorized fleet is concurrent-mode only, "
-                             "without ticks")
+        if config.tick_interval_s:
+            raise ValueError("vectorized fleet runs without ticks")
         self.config = config
         self.policy = policy if policy is not None else TimeSharingPolicy()
         self.capacity = capacity
@@ -1862,7 +1861,7 @@ class VectorizedFleetSimulator:
     def run(self, trace: list[Arrival]) -> SimResult:
         cfg = self.config
         res = SimResult(policy=getattr(self.policy, "name", "time_sharing"),
-                        window=cfg.window, jobs=[], mode="concurrent",
+                        window=cfg.window, jobs=[],
                         slice_busy_s=[0.0] * cfg.total_units,
                         pods=cfg.pods, router=cfg.router)
         if not trace:

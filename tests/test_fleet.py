@@ -13,10 +13,10 @@ Contracts pinned here:
 * **Single-pod parity** — ``SimConfig(pods=(8,))`` (and the legacy
   keyword construction path) is bit-identical to the historical
   single-pod simulator on every trace family.
-* **Unified dispatch API** — ``decide()`` is the one entry point; the
-  ``dispatch()``/``placements()`` shims raise ``DeprecationWarning`` but
-  return the same plans, and legacy subclass overrides of either are
-  still honored through ``decide()``.
+* **Unified dispatch API** — ``decide()`` is every policy's one entry
+  point: it returns the planned schedule with its width-fitted
+  placements, counts every submission as first sight or planned, and
+  never warns.
 * **Vectorized fleet parity** — the hash-routed fleet decomposes into
   independent per-pod lanes, so ``VectorizedFleetSimulator`` matches the
   heap fleet's decisions exactly and its clock to float32.
@@ -29,11 +29,14 @@ import pytest
 
 from strategies import ZOO, make_trace
 
+from repro.core import DQNAgent, EnvConfig
+from repro.core.env import CoScheduleEnv
 from repro.core.partition import N_UNITS, Partition, Slice, slice_label
-from repro.core.scheduler import DispatchDecision
+from repro.core.scheduler import to_placements
 from repro.online import (
-    ClusterSimulator, SimConfig, TRACE_FAMILIES, TimeSharingPolicy,
-    VectorizedFleetSimulator, make_router, poisson_trace,
+    ClusterSimulator, RLDispatchPolicy, SimConfig, StaticPartitionPolicy,
+    TRACE_FAMILIES, TimeSharingPolicy, VectorizedFleetSimulator, make_router,
+    poisson_trace,
 )
 from repro.online.policies import DispatchPolicy, GreedyPackerPolicy
 from repro.online.router import (
@@ -161,8 +164,6 @@ def test_simconfig_is_frozen_and_validates():
     with pytest.raises(AssertionError):
         SimConfig(pods=(8, 3))              # MIG-valid widths only
     with pytest.raises(AssertionError):
-        SimConfig(pods=(8, 4), mode="blocking")   # blocking is full-width
-    with pytest.raises(AssertionError):
         SimConfig(router="nope") and make_router("nope")
 
 
@@ -176,22 +177,29 @@ def test_summary_schema_v2_records_fleet_fields():
 
 # ------------------------------------------------------ unified decide API
 
-def test_decide_matches_deprecated_shims():
-    trace = _trace(n=20, pods=(N_UNITS,))
-    subs = [(a.binary, a.profile) for a in trace[:6]]
-    p1, p2 = TimeSharingPolicy(), TimeSharingPolicy()
-    dec = p1.decide(subs)
-    with pytest.warns(DeprecationWarning):
-        sched = p2.dispatch(subs)
-    assert dec.schedule is not None
-    from repro.core.scheduler import to_placements
-    assert [pl.partition.label for pl in dec.placements] == \
-           [pl.partition.label for pl in to_placements(sched)]
+def _rl_policy():
+    env_cfg = EnvConfig(window=4, c_max=3)
+    env = CoScheduleEnv(env_cfg)
+    return RLDispatchPolicy(DQNAgent(env.state_dim, env.n_actions, seed=0),
+                            env_cfg)
+
+
+@pytest.mark.parametrize("make_policy", [
+    TimeSharingPolicy,
+    GreedyPackerPolicy,
+    lambda: StaticPartitionPolicy("mig_mps_default"),
+    _rl_policy,
+], ids=["time_sharing", "greedy_packer", "mig_mps_default", "rl"])
+def test_decide_returns_the_width_fitted_schedule(make_policy):
+    # one call only: a second would find the binaries already profiled
+    subs = [(a.binary, a.profile) for a in _trace(n=20, pods=(N_UNITS,))]
+    dec = make_policy().decide(subs)
+
+    def labels(pls):
+        return [([j.name for j in pl.group], pl.partition.label) for pl in pls]
+
+    assert labels(dec.placements) == labels(to_placements(dec.schedule))
     assert dec.first_sight + dec.planned == len(subs)
-    with pytest.warns(DeprecationWarning):
-        pls = TimeSharingPolicy().placements(subs)
-    assert [pl.partition.label for pl in pls] == \
-           [pl.partition.label for pl in dec.placements]
 
 
 def test_decide_itself_never_warns():
@@ -205,30 +213,6 @@ def test_decide_itself_never_warns():
         warnings.simplefilter("error", DeprecationWarning)
         dec = TimeSharingPolicy().decide(subs)
     assert dec.schedule is not None and len(dec.placements) > 0
-
-
-def test_decide_honors_legacy_subclass_overrides():
-    calls = []
-
-    class LegacyDispatch(TimeSharingPolicy):
-        def dispatch(self, submissions, context=None):
-            calls.append("dispatch")
-            return super().dispatch(submissions, context=context)
-
-    class LegacyPlacements(TimeSharingPolicy):
-        def placements(self, submissions, context=None):
-            calls.append("placements")
-            return super().placements(submissions, context=context)
-
-    subs = [(a.binary, a.profile) for a in _trace(n=4, pods=(N_UNITS,))]
-    with pytest.warns(DeprecationWarning):
-        d1 = LegacyDispatch().decide(subs)
-    with pytest.warns(DeprecationWarning):
-        d2 = LegacyPlacements().decide(subs)
-    assert calls == ["dispatch", "placements"]
-    assert d1.schedule is not None and d2.schedule is not None
-    assert len(d1.placements) == len(d2.placements) > 0
-    assert isinstance(d1, DispatchDecision)
 
 
 # ----------------------------------------------------------- refit guard

@@ -1,5 +1,5 @@
 """Slice-level concurrent dispatch: placement arithmetic, occupancy
-invariants, EASY backfill, and blocking-mode bit-compatibility.
+invariants, EASY backfill, and whole-pod serving of full-pod traces.
 
 Invariant contract of the concurrent event model:
 
@@ -9,9 +9,9 @@ Invariant contract of the concurrent event model:
     from segments equal ``SimResult.slice_busy_s``, and the union of
     segment intervals equals ``busy_time``;
   * backfill never delays the blocked head's start (EASY reservation);
-  * on traces without sub-pod width hints, ``mode="concurrent"`` is
-    bit-compatible with the PR-3 ``mode="blocking"`` dispatch, which stays
-    available for regression.
+  * on traces without sub-pod width hints every group claims the whole
+    pod, so groups run back to back, each for its solo makespan, nothing
+    is backfilled, and every window sees an idle pod.
 """
 import dataclasses
 
@@ -168,8 +168,6 @@ def test_concurrent_mode_actually_overlaps_on_fragmented_trace():
                    if segs[i].t0 < segs[j].t1 - 1e-9
                    and segs[j].t0 < segs[i].t1 - 1e-9)
     assert overlaps > 0, "no concurrency on a width-mixed trace"
-    assert res.throughput > ClusterSimulator(
-        TimeSharingPolicy(), window=6, mode="blocking").run(trace).throughput
 
 
 def test_simulator_concurrent_deterministic():
@@ -234,37 +232,25 @@ def test_lookahead_window_backfills_later_arrival():
     assert res.dispatches == 2                             # lookahead window
 
 
-# ------------------------------------------- blocking-mode compatibility
+# ---------------------------------------------- full-pod traces, whole pod
 
 @pytest.mark.parametrize("window", [1, 4])
-def test_concurrent_bit_compatible_with_blocking_on_full_pod_traces(window):
-    """Without sub-pod width hints every placement is full-pod, so the
-    slice-level engine must reproduce the PR-3 blocking results exactly
-    (records bit-equal; busy time to float accumulation order)."""
+def test_full_pod_trace_serves_whole_pod_blocks_in_sequence(window):
+    """Without sub-pod width hints every placement is full-pod: each group
+    claims all units, groups never overlap in time, each job runs for its
+    solo makespan, and nothing is backfilled."""
     trace = poisson_trace(ZOO, n=25, seed=3)
-    blk = ClusterSimulator(TimeSharingPolicy(), window=window,
-                           mode="blocking").run(trace)
-    con = ClusterSimulator(TimeSharingPolicy(), window=window).run(trace)
-    assert [(j.dispatch, j.finish, j.group_size, j.partition)
-            for j in blk.jobs] == \
-           [(j.dispatch, j.finish, j.group_size, j.partition)
-            for j in con.jobs]
-    sb, sc = blk.summary(), con.summary()
-    assert sb["mode"] == "blocking" and sc["mode"] == "concurrent"
-    for k in sb:
-        if k in ("mode", "busy_s", "utilization"):
-            continue
-        assert sb[k] == pytest.approx(sc[k]), k
-    assert np.isclose(sb["busy_s"], sc["busy_s"])
-    assert con.backfills == 0                      # nothing to backfill
-
-
-def test_blocking_mode_segments_claim_full_pod():
-    trace = poisson_trace(ZOO, n=10, seed=1)
-    res = ClusterSimulator(TimeSharingPolicy(), window=4,
-                           mode="blocking").run(trace)
+    res = ClusterSimulator(TimeSharingPolicy(), window=window).run(trace)
     assert all(s.slices == ((0, N_UNITS),) for s in res.timeline)
+    segs = sorted(res.timeline, key=lambda s: s.t0)
+    for a, b in zip(segs, segs[1:]):
+        assert a.t1 <= b.t0 + 1e-9, (a, b)
+    profile = {a.profile.name: a.profile for a in trace}
+    for rec in res.jobs:
+        solo = corun([profile[rec.name]], solo_partition()).makespan
+        assert rec.finish - rec.dispatch == pytest.approx(solo)
     assert np.isclose(res.unit_busy_s, N_UNITS * res.busy_time)
+    assert res.backfills == 0
 
 
 # --------------------------------------------- dispatch-time context snapshot
@@ -276,9 +262,9 @@ class _RecordingPolicy(TimeSharingPolicy):
         super().__init__()
         self.contexts = []
 
-    def placements(self, submissions, context=None):
+    def decide(self, submissions, context=None):
         self.contexts.append((context, [p for p, _ in submissions]))
-        return super().placements(submissions, context=context)
+        return super().decide(submissions, context=context)
 
 
 def test_dispatch_context_matches_occupancy_and_ages():
@@ -316,19 +302,13 @@ def test_dispatch_context_matches_occupancy_and_ages():
     assert partial > 0
 
 
-def test_blocking_dispatch_context_reports_idle_pod():
-    trace = poisson_trace(ZOO, n=12, seed=1)
-
-    class _Rec(TimeSharingPolicy):
-        seen = []
-
-        def dispatch(self, submissions, context=None):
-            self.seen.append(context)
-            return super().dispatch(submissions, context=context)
-
-    pol = _Rec()
-    ClusterSimulator(pol, window=4, mode="blocking").run(trace)
-    assert pol.seen and all(all(c.free_units) for c in pol.seen)
+def test_dispatch_context_reports_idle_pod_on_full_pod_traces():
+    """A full-pod claim leaves no unit free, so every window of a hint-free
+    trace forms on an idle pod."""
+    trace = poisson_trace(ZOO, n=25, seed=3)
+    pol = _RecordingPolicy()
+    ClusterSimulator(pol, window=4).run(trace)
+    assert pol.contexts and all(all(c.free_units) for c, _ in pol.contexts)
 
 
 # ------------------------------------------------------ fragmented trace

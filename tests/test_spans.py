@@ -269,22 +269,3 @@ def test_spans_land_on_the_profiler_host_plane(tmp_path):
     # scale: both time the same enter/exit on the host
     (act,) = [s for s in spans.records() if s.name == "repro.agent.act"]
     assert abs((act.end_ns - act.start_ns) - (hi - lo)) < 1e6
-
-
-def test_online_sim_profile_reads_the_recorder():
-    from benchmarks.online_sim import _simulate
-
-    trace = poisson_trace(ZOO, n=40, load=1.25, seed=4)
-    policy = RLDispatchPolicy(_agent(), ENV_CFG)
-    out = _simulate(policy, trace, ENV_CFG.window, profile=True)
-    prof = out["profile"]
-    assert set(prof) == {"policy_s", "retrain_s", "sim_s"}
-    assert 0.0 < prof["policy_s"] <= out["sim_wall_s"]
-    assert prof["retrain_s"] == 0.0
-    assert prof["sim_s"] == pytest.approx(
-        out["sim_wall_s"] - prof["policy_s"])
-    assert spans.span("repro.x") is spans.NOOP and spans.records() == []
-    assert "decide" not in vars(policy)       # no instance patch left behind
-    plain = _simulate(RLDispatchPolicy(_agent(), ENV_CFG), trace,
-                      ENV_CFG.window)
-    assert out["throughput"] == plain["throughput"]

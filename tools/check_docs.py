@@ -15,7 +15,7 @@ import sys
 
 LINK = re.compile(r"\[[^\]]*\]\(([^)#\s]+)(?:#[^)]*)?\)")
 # `path/to/file.py` style inline-code refs that look like repo paths
-CODE_PATH = re.compile(r"`((?:src|tests|benchmarks|examples|docs|tools|"
+CODE_PATH = re.compile(r"`((?:src|tests|examples|docs|tools|"
                        r"\.github)/[A-Za-z0-9_./\-]+)`")
 
 
